@@ -25,14 +25,14 @@ bench:
 # fallback past corruption, serving abort/retry/re-jit parity, elastic
 # shrink on device dropout.
 chaos:
-	JAX_PLATFORMS=cpu REPRO_PALLAS_INTERPRET=1 $(PY) scripts/chaos.py
+	JAX_PLATFORMS=cpu $(PY) scripts/chaos.py
 
 # Observability artifacts (DESIGN.md §12): drive train + paged-serve with
 # every pillar on and validate the Prometheus text grammar, the Chrome
 # trace schema + >=95% span coverage, the JSONL event log, and the
 # per-expert router invariant sum(expert_tokens) == top_k * routed.
 obs-check:
-	JAX_PLATFORMS=cpu REPRO_PALLAS_INTERPRET=1 $(PY) scripts/obs_check.py
+	JAX_PLATFORMS=cpu $(PY) scripts/obs_check.py
 
 # Every `DESIGN.md §N` citation in src/ must resolve to a `## §N` heading,
 # and every public API in parallel/ + runtime/ + quant/ + launch/ must

@@ -135,14 +135,16 @@ print("RESULT" + json.dumps(out))
 """
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
+    # The child's 8 host devices are the CPU backend's; a chip, if any,
+    # belongs to the parent process.
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=900)
     line = [l for l in res.stdout.splitlines() if l.startswith("RESULT")]
     if not line:
-        emit("ablation_F12/cache_policy/ERROR", 0.0,
-             res.stderr.strip()[-200:].replace(",", ";"))
-        return
+        raise RuntimeError(
+            "cache-policy child printed no RESULT line "
+            f"(exit {res.returncode}):\n{res.stderr[-3000:]}")
     out = json.loads(line[0][len("RESULT"):])
     for policy, peak in out.items():
         emit(f"ablation_F12/cache_policy/{policy}", 0.0,

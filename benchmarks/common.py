@@ -49,8 +49,6 @@ def compiled_stats(fn, *abstract_args) -> dict:
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict] per device
-        ca = ca[0] if ca else {}
     return {
         "peak_bytes": ma.argument_size_in_bytes + ma.temp_size_in_bytes,
         "temp_bytes": ma.temp_size_in_bytes,

@@ -44,8 +44,8 @@ def bench_cfg(scale: str, num_experts: int, top_k: int) -> swin.SwinConfig:
     )
 
 
-def make_train_fn(cfg, pcfg, moe_impl):
-    opt_cfg = adamw.OptimizerConfig(master_fp32=False)
+def make_loss_fn(cfg, pcfg, moe_impl):
+    """Classification loss of a Swin-MoE: cross-entropy + 0.01 * aux."""
 
     def loss_fn(params, images, labels):
         logits, aux, z = swin.swin_forward(
@@ -54,6 +54,13 @@ def make_train_fn(cfg, pcfg, moe_impl):
         onehot = jax.nn.one_hot(labels, cfg.num_classes)
         ce = -jnp.mean(jnp.sum(jax.nn.log_softmax(logits) * onehot, -1))
         return ce + 0.01 * aux
+
+    return loss_fn
+
+
+def make_train_fn(cfg, pcfg, moe_impl):
+    opt_cfg = adamw.OptimizerConfig(master_fp32=False)
+    loss_fn = make_loss_fn(cfg, pcfg, moe_impl)
 
     def train(params, opt, images, labels):
         loss, grads = jax.value_and_grad(loss_fn)(params, images, labels)

@@ -14,10 +14,9 @@ if git ls-files '*.pyc' '**/__pycache__/*' | grep -q .; then
     exit 1
 fi
 
-# Tier-1 on CPU; Pallas kernels run in interpret mode off-TPU (this is the
-# default in repro.common.pallas_interpret_default, forced here for clarity).
+# Tier-1 on CPU, where Pallas kernels run in interpret mode
+# (repro.common.pallas_interpret_default).
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export REPRO_PALLAS_INTERPRET=1
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 # Two test tiers (tests/conftest.py markers): tier-1 fast in-process tests

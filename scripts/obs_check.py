@@ -33,7 +33,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 SERIES_RE = re.compile(
     r'^[a-zA-Z_:][a-zA-Z0-9_:]*'            # metric name

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import functools
 import os
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -28,32 +29,39 @@ def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
 
 
+#: The checkout root: ``<root>/src/repro/common.py``.
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(None)
 def pallas_interpret_default() -> bool:
-    """Pallas kernels run in interpret mode everywhere except real TPU."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return not on_tpu()
+    """Pallas kernels compile through Mosaic on a TPU and run in interpret
+    mode on the CPU. Any other backend has no lowering for them: error."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels cannot run on backend {backend!r}")
+    return backend == "cpu"
 
 
-def tpu_compiler_params(**kwargs):
-    """Mosaic compiler params across jax versions: the class is
-    ``pltpu.CompilerParams`` on 2025-era jax but ``TPUCompilerParams`` on
-    the 0.4.x line this toolchain pins."""
-    from jax.experimental.pallas import tpu as pltpu
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, because the path is part of the cache key. Entry points
+    call this before their first compile; tests never do, so they run with
+    the cache off."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_bytes(tree: Any) -> int:

@@ -1,18 +1,20 @@
 """ESFFN — fused expert-FFN megakernel (Pallas TPU). DESIGN.md §5.
 
-ONE ``pallas_call`` runs the whole expert FFN over the expert-sorted layout:
+ONE ``pallas_call`` runs the whole expert FFN over the expert-sorted rows
+``xs = core.reindex.gather_rows(x, row_token)``:
 
-  gather -> up/gate ESMM -> activation -> down ESMM -> gate-weighted output
+  up/gate ESMM -> activation -> down ESMM -> gate-weighted output
 
 Per BLK_M block the kernel
 
-  1. DMA-gathers its token rows straight out of the *unsorted* ``(N, D)``
-     activations via the scalar-prefetched ``row_token`` map — the
-     ``(Np, D)`` ``gather_sorted`` copy is never materialised in HBM,
+  1. reads its ``(BLK_M, D)`` tile of the sorted rows (the caller gathers
+     them with one XLA gather: a one-row DMA per token out of the unsorted
+     ``(N, D)`` activations is refused by Mosaic, a single row not being
+     aligned to the (8, 128) / packed (16, 128) HBM tiling),
   2. computes the up/gate projections against the scalar-prefetched expert
      weight tiles, sharing the single VMEM-resident x tile between gate and
      up in the GLU case,
-  3. applies the activation on the VPU,
+  3. applies the activation on the VPU in float32,
   4. accumulates the down projection in a float32 VMEM accumulator across
      hidden-dim tiles, and
   5. writes the gate-weighted sorted output (combine-ready: the caller's
@@ -24,9 +26,9 @@ and the output — the forward analogue of the ESFK backward fusion
 (DESIGN.md §2), and the dominant inter-stage traffic the unfused
 gather/esmm/act/esmm/combine composition round-trips through HBM.
 
-Padding rows (``row_token == N``) clamp their gather to row ``N-1``; the
-garbage they compute is annihilated by their zero combine gate, which is
-applied in-kernel before the write.
+Padding rows are the zero rows ``gather_rows`` gives sentinel tokens;
+whatever they compute (a bias, at most) is annihilated by their zero
+combine gate, which is applied in-kernel before the write.
 
 Backward is flash-style recompute, wired in ``kernels.ops``: only xs-level
 residuals are saved and the hidden is rebuilt tile-wise from the existing
@@ -41,8 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import ACTIVATIONS, pallas_interpret_default, tpu_compiler_params
-from repro.quant.core import dequant_tile, scale_block_dims
+from repro.common import ACTIVATIONS, pallas_interpret_default
+from repro.quant.core import dequant_tile, tile_scales
 
 _CONTRACT_K = (((1,), (0,)), ((), ()))  # row-major matmul: (m,k)x(k,n)
 
@@ -64,8 +66,10 @@ def esffn_cost(
     ``bytes_accessed`` counts the gathered token rows, one expert weight
     tile per M-block, the gate vector and the sorted output — and, by
     construction, EXCLUDES the (Np, F) hidden round-trip the unfused
-    composition pays (2 HBM writes + 2..3 reads of g/u/h between stages)
-    plus the (Np, D) sorted-copy round-trip of ``gather_sorted``.
+    composition pays (2 HBM writes + 2..3 reads of g/u/h between stages).
+    The row gather ahead of the call (``gather_rows``) is an XLA op of its
+    own: its (Np, D) write and the kernel's read of it are the sorted-copy
+    round-trip the autotune model charges the fused path too.
 
     ``weight_bits`` (DESIGN.md §8) overrides the weight itemsize for
     quantized experts: int8/fp8 payloads move 8 bits per element across
@@ -90,47 +94,19 @@ def esffn_cost(
     )
 
 
-def _gather_block(x_any, rt_ref, x_s, sem, m, bm, n_tokens):
-    """DMA rows ``row_token[m*bm : (m+1)*bm]`` of the unsorted x into VMEM.
-
-    Sentinel rows (token id == n_tokens) clamp to the last real row: their
-    values are annihilated by the zero combine gate at write-out, so any
-    finite row serves. All row copies are started before any is awaited so
-    Mosaic can keep the full gather in flight.
-    """
-    base = m * bm
-
-    def start(i, _):
-        tok = jnp.minimum(rt_ref[base + i], n_tokens - 1)
-        pltpu.make_async_copy(x_any.at[tok], x_s.at[i], sem).start()
-        return _
-
-    jax.lax.fori_loop(0, bm, start, None)
-
-    def wait(i, _):
-        # Waits are by byte count: any same-shaped descriptor drains one row.
-        pltpu.make_async_copy(x_any.at[0], x_s.at[0], sem).wait()
-        return _
-
-    jax.lax.fori_loop(0, bm, wait, None)
-
-
 def _wtile(w_ref, s_ref):
     """One expert weight tile, dequantized in VMEM when quantized
     (DESIGN.md §8) — only the int8/fp8 bytes crossed HBM."""
     if s_ref is None:
         return w_ref[0]
-    return dequant_tile(w_ref[0], s_ref[0])
+    return dequant_tile(w_ref[0], s_ref[0, 0, 0])
 
 
 def _esffn_glu_kernel(
     block_expert,  # scalar prefetch (num_blocks,)
-    row_token,     # scalar prefetch (Np,)
-    x_any,         # (N, D) unsorted tokens, ANY/HBM
-    *rest,         # wg [sg] wu [su] wd [sd] gate o x_s acc sem
+    x_ref,         # (BLK_M, D) gathered token rows
+    *rest,         # wg [sg] wu [su] wd [sd] gate o acc
     act_fn,
-    bm: int,
-    n_tokens: int,
     quantized: bool,
 ):
     rest = list(rest)
@@ -140,18 +116,16 @@ def _esffn_glu_kernel(
     su_ref = rest.pop(0) if quantized else None
     wd_ref = rest.pop(0)
     sd_ref = rest.pop(0) if quantized else None
-    gate_ref, o_ref, x_s, acc, sem = rest
+    gate_ref, o_ref, acc = rest
 
-    m = pl.program_id(0)
     fb = pl.program_id(1)
     nf = pl.num_programs(1)
 
     @pl.when(fb == 0)
-    def _load():
-        _gather_block(x_any, row_token, x_s, sem, m, bm, n_tokens)
+    def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = x_s[...]
+    x = x_ref[...]
     # One read of the x tile feeds BOTH projections (the GLU sharing).
     g = jax.lax.dot_general(
         x, _wtile(wg_ref, sg_ref), _CONTRACT_K,
@@ -161,7 +135,9 @@ def _esffn_glu_kernel(
         x, _wtile(wu_ref, su_ref), _CONTRACT_K,
         preferred_element_type=jnp.float32,
     ).astype(x.dtype)
-    h = act_fn(g) * u  # (BLK_M, BLK_F), VMEM only — never written to HBM
+    # (BLK_M, BLK_F), VMEM only — never written to HBM. The activation
+    # runs in float32: Mosaic has no bf16 lowering of its constants.
+    h = (act_fn(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
     acc[...] += jax.lax.dot_general(
         h, _wtile(wd_ref, sd_ref), _CONTRACT_K,
         preferred_element_type=jnp.float32,
@@ -176,14 +152,11 @@ def _esffn_glu_kernel(
 
 def _esffn_mlp_kernel(
     block_expert,
-    row_token,
-    x_any,
+    x_ref,         # (BLK_M, D) gathered token rows
     w1_ref,        # (1, D, BLK_F)
-    *rest,         # [s1], [b1 (1, BLK_F)], w2 (1, BLK_F, D), [s2],
-                   # [b2 (1, D)], gate, o, x_s, acc, sem
+    *rest,         # [s1], [b1 (1, 1, BLK_F)], w2 (1, BLK_F, D), [s2],
+                   # [b2 (1, 1, D)], gate, o, acc
     act_fn,
-    bm: int,
-    n_tokens: int,
     has_b1: bool,
     has_b2: bool,
     quantized: bool,
@@ -194,15 +167,13 @@ def _esffn_mlp_kernel(
     w2_ref = rest.pop(0)
     s2_ref = rest.pop(0) if quantized else None
     b2_ref = rest.pop(0) if has_b2 else None
-    gate_ref, o_ref, x_s, acc, sem = rest
+    gate_ref, o_ref, acc = rest
 
-    m = pl.program_id(0)
     fb = pl.program_id(1)
     nf = pl.num_programs(1)
 
     @pl.when(fb == 0)
-    def _load():
-        _gather_block(x_any, row_token, x_s, sem, m, bm, n_tokens)
+    def _init():
         if has_b2:
             # b2 is added once per row, not once per hidden tile.
             acc[...] = jnp.broadcast_to(
@@ -211,14 +182,14 @@ def _esffn_mlp_kernel(
         else:
             acc[...] = jnp.zeros_like(acc)
 
-    x = x_s[...]
+    x = x_ref[...]
     z = jax.lax.dot_general(
         x, _wtile(w1_ref, s1_ref), _CONTRACT_K,
         preferred_element_type=jnp.float32,
     )
     if has_b1:
         z = z + b1_ref[0].astype(jnp.float32)
-    h = act_fn(z.astype(x.dtype))
+    h = act_fn(z.astype(x.dtype).astype(jnp.float32)).astype(x.dtype)
     acc[...] += jax.lax.dot_general(
         h, _wtile(w2_ref, s2_ref), _CONTRACT_K,
         preferred_element_type=jnp.float32,
@@ -231,10 +202,9 @@ def _esffn_mlp_kernel(
         ).astype(o_ref.dtype)
 
 
-def _call(kernel, x, row_token, row_gate, block_expert, tensor_args,
+def _call(kernel, xs, row_gate, block_expert, tensor_args,
           tensor_specs, f_dim, bf, cost, interpret):
-    n, d = x.shape
-    np_rows = row_token.shape[0]
+    np_rows, d = xs.shape
     nm = block_expert.shape[0]
     assert np_rows % nm == 0, (np_rows, nm)
     bm = np_rows // nm
@@ -242,44 +212,39 @@ def _call(kernel, x, row_token, row_gate, block_expert, tensor_args,
     assert f_dim % bf == 0, (f_dim, bf)
     grid = (nm, f_dim // bf)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] + tensor_specs + [
-        pl.BlockSpec((bm, 1), lambda m, fb, be, rt: (m, 0)),
-    ]
+    in_specs = [pl.BlockSpec((bm, d), lambda m, fb, be: (m, 0))] + (
+        tensor_specs + [pl.BlockSpec((bm, 1), lambda m, fb, be: (m, 0))]
+    )
     return pl.pallas_call(
-        functools.partial(kernel, bm=bm, n_tokens=n),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=1,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((bm, d), lambda m, fb, be, rt: (m, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bm, d), x.dtype),
-                pltpu.VMEM((bm, d), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-            ],
+            out_specs=pl.BlockSpec((bm, d), lambda m, fb, be: (m, 0)),
+            scratch_shapes=[pltpu.VMEM((bm, d), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((np_rows, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((np_rows, d), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=cost,
         interpret=interpret,
-    )(block_expert, row_token, x, *tensor_args,
+    )(block_expert, xs, *tensor_args,
       row_gate.reshape(np_rows, 1).astype(jnp.float32))
 
 
-def _scale_spec(wdims, sdims, bdims, index_map):
-    """BlockSpec of a weight's scale operand, congruent with its weight
-    BlockSpec (each per-axis quant tile must divide the kernel block)."""
-    return pl.BlockSpec(
-        (1,) + scale_block_dims(wdims, sdims, bdims), index_map
-    )
+def _scale_arg(sc, wdims, bdims, index_map):
+    """A weight's scale operand, tiled congruently with its weight
+    BlockSpec (``quant.core.tile_scales``), and that operand's BlockSpec."""
+    st = tile_scales(sc, wdims, bdims)
+    return st, pl.BlockSpec((1, 1, 1) + st.shape[3:],
+                            lambda *a: index_map(*a) + (0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("act", "bf", "interpret"))
 def esffn_glu_pallas(
-    x: jax.Array,
-    row_token: jax.Array,
+    xs: jax.Array,
     row_gate: jax.Array,
     block_expert: jax.Array,
     w_gate: jax.Array,
@@ -291,18 +256,19 @@ def esffn_glu_pallas(
     bf: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused GLU expert FFN: (N, D) unsorted tokens -> (Np, D) gate-weighted
+    """Fused GLU expert FFN: (Np, D) sorted rows -> (Np, D) gate-weighted
     sorted output, in one Pallas pass.
 
-    x: (N, D); row_token/row_gate: (Np,) from ``core.reindex``; block_expert:
-    (Np // blk,); w_gate/w_up: (E, D, F); w_down: (E, F, D). ``w_scales``
+    xs: (Np, D) from ``core.reindex.gather_rows``; row_gate: (Np,) and
+    block_expert: (Np // blk,) from ``core.reindex``; w_gate/w_up:
+    (E, D, F); w_down: (E, F, D). ``w_scales``
     (DESIGN.md §8): (sg, su, sd) block-wise scales of int8/fp8 weights —
     each weight tile is dequantized in VMEM right before its MXU
     contraction, so the quantized bytes are what cross HBM.
     """
     if interpret is None:
         interpret = pallas_interpret_default()
-    n, d = x.shape
+    d = xs.shape[1]
     e, dw, f = w_gate.shape
     assert dw == d and w_up.shape == (e, d, f) and w_down.shape == (e, f, d)
     nm = block_expert.shape[0]
@@ -311,8 +277,8 @@ def esffn_glu_pallas(
     kernel = functools.partial(
         _esffn_glu_kernel, act_fn=ACTIVATIONS[act], quantized=quantized
     )
-    up_map = lambda m, fb, be, rt: (be[m], 0, fb)    # noqa: E731
-    down_map = lambda m, fb, be, rt: (be[m], fb, 0)  # noqa: E731
+    up_map = lambda m, fb, be: (be[m], 0, fb)    # noqa: E731
+    down_map = lambda m, fb, be: (be[m], fb, 0)  # noqa: E731
     args, specs = [], []
     for wt, sc, wdims, bdims, imap in (
         (w_gate, None if not quantized else w_scales[0], (d, f),
@@ -325,20 +291,20 @@ def esffn_glu_pallas(
         args.append(wt)
         specs.append(pl.BlockSpec((1,) + bdims, imap))
         if quantized:
-            args.append(sc)
-            specs.append(_scale_spec(wdims, sc.shape[1:], bdims, imap))
+            st, spec = _scale_arg(sc, wdims, bdims, imap)
+            args.append(st)
+            specs.append(spec)
     cost = esffn_cost(
-        row_token.shape[0], d, f, nm, x.dtype.itemsize, glu=True,
+        xs.shape[0], d, f, nm, xs.dtype.itemsize, glu=True,
         weight_bits=8 * w_gate.dtype.itemsize,
     )
-    return _call(kernel, x, row_token, row_gate, block_expert,
+    return _call(kernel, xs, row_gate, block_expert,
                  args, specs, f, bf, cost, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "bf", "interpret"))
 def esffn_mlp_pallas(
-    x: jax.Array,
-    row_token: jax.Array,
+    xs: jax.Array,
     row_gate: jax.Array,
     block_expert: jax.Array,
     w1: jax.Array,
@@ -359,7 +325,7 @@ def esffn_mlp_pallas(
     """
     if interpret is None:
         interpret = pallas_interpret_default()
-    n, d = x.shape
+    d = xs.shape[1]
     e, dw, f = w1.shape
     assert dw == d and w2.shape == (e, f, d)
     nm = block_expert.shape[0]
@@ -369,32 +335,34 @@ def esffn_mlp_pallas(
         _esffn_mlp_kernel, act_fn=ACTIVATIONS[act],
         has_b1=b1 is not None, has_b2=b2 is not None, quantized=quantized,
     )
-    up_map = lambda m, fb, be, rt: (be[m], 0, fb)    # noqa: E731
-    down_map = lambda m, fb, be, rt: (be[m], fb, 0)  # noqa: E731
+    up_map = lambda m, fb, be: (be[m], 0, fb)    # noqa: E731
+    down_map = lambda m, fb, be: (be[m], fb, 0)  # noqa: E731
     args = [w1]
     specs = [pl.BlockSpec((1, d, bf_r), up_map)]
     if quantized:
-        args.append(w_scales[0])
-        specs.append(_scale_spec((d, f), w_scales[0].shape[1:],
-                                 (d, bf_r), up_map))
+        st, spec = _scale_arg(w_scales[0], (d, f), (d, bf_r), up_map)
+        args.append(st)
+        specs.append(spec)
+    # Biases ride as (E, 1, width): a (1, 1, block) tile satisfies the
+    # (8, 128) block rule, a (1, block) tile of (E, width) does not.
     if b1 is not None:
         assert b1.shape == (e, f)
-        args.append(b1)
-        specs.append(pl.BlockSpec((1, bf_r), lambda m, fb, be, rt: (be[m], fb)))
+        args.append(b1.reshape(e, 1, f))
+        specs.append(pl.BlockSpec((1, 1, bf_r), up_map))
     args.append(w2)
     specs.append(pl.BlockSpec((1, bf_r, d), down_map))
     if quantized:
-        args.append(w_scales[1])
-        specs.append(_scale_spec((f, d), w_scales[1].shape[1:],
-                                 (bf_r, d), down_map))
+        st, spec = _scale_arg(w_scales[1], (f, d), (bf_r, d), down_map)
+        args.append(st)
+        specs.append(spec)
     if b2 is not None:
         assert b2.shape == (e, d)
-        args.append(b2)
-        specs.append(pl.BlockSpec((1, d), lambda m, fb, be, rt: (be[m], 0)))
+        args.append(b2.reshape(e, 1, d))
+        specs.append(pl.BlockSpec((1, 1, d), lambda m, fb, be: (be[m], 0, 0)))
     cost = esffn_cost(
-        row_token.shape[0], d, f, nm, x.dtype.itemsize, glu=False,
+        xs.shape[0], d, f, nm, xs.dtype.itemsize, glu=False,
         has_b1=b1 is not None, has_b2=b2 is not None,
         weight_bits=8 * w1.dtype.itemsize,
     )
-    return _call(kernel, x, row_token, row_gate, block_expert,
+    return _call(kernel, xs, row_gate, block_expert,
                  args, specs, f, bf, cost, interpret)

@@ -15,9 +15,11 @@ Grid is (d1_blocks, d2_blocks, m_blocks) with m innermost so that revisits of
 the accumulator output block (one per expert) are consecutive — the sorted
 layout guarantees equal experts occupy consecutive m blocks.
 
-The db output carries one junk row (shape (E+1, D2)): for d1-block index
-i > 0 the kernel parks its write target on row E so the auto copy-back of the
-revisited buffer never corrupts real rows. Caller slices [:E].
+The db output carries one junk row (shape (E+1, 1, D2), the unit axis
+keeping its (1, 1, BLK_D2) tile within the (8, 128) block rule): for
+d1-block index i > 0 the kernel parks its write target on row E so the auto
+copy-back of the revisited buffer never corrupts real rows. Caller slices
+[:E].
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import pallas_interpret_default, tpu_compiler_params
+from repro.common import pallas_interpret_default
 
 
 def _esfk_kernel(
@@ -36,7 +38,7 @@ def _esfk_kernel(
     x1_ref,        # (BLK_M, BLK_D1)
     x2_ref,        # (BLK_M, BLK_D2)
     dw_ref,        # (1, BLK_D1, BLK_D2)
-    db_ref,        # (1, BLK_D2)
+    db_ref,        # (1, 1, BLK_D2)
     acc_dw,        # VMEM (BLK_D1, BLK_D2) f32
     acc_db,        # VMEM (1, BLK_D2) f32
 ):
@@ -73,7 +75,7 @@ def _esfk_kernel(
     @pl.when(is_last)
     def _done():
         dw_ref[...] = acc_dw[...][None].astype(dw_ref.dtype)
-        db_ref[...] = acc_db[...].astype(db_ref.dtype)
+        db_ref[0] = acc_db[...].astype(db_ref.dtype)
 
 
 @functools.partial(
@@ -134,8 +136,8 @@ def esfk_pallas(
                 ),
                 # Junk-row parking for i > 0 (see module docstring).
                 pl.BlockSpec(
-                    (1, b2),
-                    lambda i, j, m, be: (jnp.where(i == 0, be[m], e), j),
+                    (1, 1, b2),
+                    lambda i, j, m, be: (jnp.where(i == 0, be[m], e), 0, j),
                 ),
             ],
             scratch_shapes=[
@@ -145,9 +147,9 @@ def esfk_pallas(
         ),
         out_shape=[
             jax.ShapeDtypeStruct((e, d1, d2), jnp.float32),
-            jax.ShapeDtypeStruct((e + 1, d2), jnp.float32),
+            jax.ShapeDtypeStruct((e + 1, 1, d2), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -160,5 +162,5 @@ def esfk_pallas(
     # HBM output blocks are undefined. Mask them to exact zeros.
     has = counts > 0
     dw = jnp.where(has[:, None, None], dw, 0.0)
-    db = jnp.where(has[:, None], db_full[:e], 0.0)
+    db = jnp.where(has[:, None], db_full[:e, 0], 0.0)
     return dw, db

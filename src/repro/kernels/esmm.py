@@ -28,8 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import cdiv, pallas_interpret_default, tpu_compiler_params
-from repro.quant.core import dequant_tile, scale_block_dims
+from repro.common import pallas_interpret_default
+from repro.quant.core import dequant_tile, tile_scales
 
 
 def _esmm_kernel(
@@ -60,7 +60,7 @@ def _esmm_kernel(
     w = w_ref[0]
     if has_scale:
         # VMEM dequant right before the contraction (DESIGN.md §8).
-        w = dequant_tile(w, s_ref[0])
+        w = dequant_tile(w, s_ref[0, 0, 0])
     # transposed: w block is (BLK_N, BLK_K); contract x dim 1 with w dim 1.
     dims = (((1,), (1,)), ((), ())) if transpose else (((1,), (0,)), ((), ()))
     acc_ref[...] += jax.lax.dot_general(
@@ -133,17 +133,18 @@ def esmm_pallas(
     if w_scales is not None:
         assert w_scales.shape[0] == e, (w_scales.shape, w.shape)
         if transpose_rhs:
-            sb = scale_block_dims((d2, d1), w_scales.shape[1:], (bn, bk))
-            in_specs.append(pl.BlockSpec(
-                (1,) + sb, lambda i, j, k, be: (be[i], j, k)))
+            st = tile_scales(w_scales, (d2, d1), (bn, bk))
+            s_map = lambda i, j, k, be: (be[i], j, k, 0, 0)  # noqa: E731
         else:
-            sb = scale_block_dims((d1, d2), w_scales.shape[1:], (bk, bn))
-            in_specs.append(pl.BlockSpec(
-                (1,) + sb, lambda i, j, k, be: (be[i], k, j)))
-        args.append(w_scales)
+            st = tile_scales(w_scales, (d1, d2), (bk, bn))
+            s_map = lambda i, j, k, be: (be[i], k, j, 0, 0)  # noqa: E731
+        in_specs.append(pl.BlockSpec((1, 1, 1) + st.shape[3:], s_map))
+        args.append(st)
     if b is not None:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k, be: (be[i], j)))
-        args.append(b)
+        # (E, 1, D2) so the (1, 1, bn) tile meets the (8, 128) block rule.
+        in_specs.append(
+            pl.BlockSpec((1, 1, bn), lambda i, j, k, be: (be[i], 0, j)))
+        args.append(b.reshape(e, 1, d2))
 
     flops = 2 * np_rows * d1 * d2
     bytes_accessed = (
@@ -165,7 +166,7 @@ def esmm_pallas(
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((np_rows, d2), xs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import pallas_interpret_default, tpu_compiler_params
+from repro.common import pallas_interpret_default
 
 
 def _ess_kernel(block_expert, x_ref, o_ref, acc_ref):
@@ -36,7 +36,7 @@ def _ess_kernel(block_expert, x_ref, o_ref, acc_ref):
 
     @pl.when(cur != nxt)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bd", "interpret"))
@@ -66,11 +66,12 @@ def ess_pallas(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[pl.BlockSpec((bm, bd), lambda j, m, be: (m, j))],
-            out_specs=pl.BlockSpec((1, bd), lambda j, m, be: (be[m], j)),
+            # (E, 1, D) output: a (1, 1, bd) tile meets the (8, 128) rule.
+            out_specs=pl.BlockSpec((1, 1, bd), lambda j, m, be: (be[m], 0, j)),
             scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((e, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((e, 1, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -80,4 +81,4 @@ def ess_pallas(
         ),
         interpret=interpret,
     )(block_expert, x)
-    return jnp.where((counts > 0)[:, None], out, 0.0)
+    return jnp.where((counts > 0)[:, None], out[:, 0], 0.0)

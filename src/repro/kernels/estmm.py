@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import pallas_interpret_default, tpu_compiler_params
+from repro.common import pallas_interpret_default
 
 
 def _estmm_kernel(block_expert, x1_ref, x2_ref, o_ref, acc_ref):
@@ -79,7 +79,7 @@ def estmm_pallas(
             scratch_shapes=[pltpu.VMEM((b1, b2), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, d1, d2), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -135,28 +135,18 @@ def _full_group_sizes(padded_counts: jax.Array, np_rows) -> jax.Array:
     return padded_counts.at[-1].add(tail.astype(padded_counts.dtype))
 
 
-#: jax 0.4.x only ships the fixed-layout lax.ragged_dot; the general
-#: dimension-numbers form arrived later. Fall back where possible.
-_HAS_RAGGED_DN = hasattr(lax, "RaggedDotDimensionNumbers")
-
-
 def _ragged_esmm(xs, w, b, block_expert, padded_counts, transpose_rhs):
     np_rows = xs.shape[0]
     gs = _full_group_sizes(padded_counts, np_rows)
     if transpose_rhs:
-        if _HAS_RAGGED_DN:
-            dn = lax.RaggedDotDimensionNumbers(
-                dot_dimension_numbers=(((1,), (2,)), ((), ())),
-                lhs_ragged_dimensions=[0],
-                rhs_group_dimensions=[0],
-            )
-            y = lax.ragged_dot_general(
-                xs, w, gs, dn, preferred_element_type=xs.dtype
-            )
-        else:  # materialise the transpose; XLA folds it into the dot
-            y = lax.ragged_dot(
-                xs, jnp.swapaxes(w, 1, 2), gs, preferred_element_type=xs.dtype
-            )
+        dn = lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((1,), (2,)), ((), ())),
+            lhs_ragged_dimensions=[0],
+            rhs_group_dimensions=[0],
+        )
+        y = lax.ragged_dot_general(
+            xs, w, gs, dn, preferred_element_type=xs.dtype
+        )
     else:
         y = lax.ragged_dot(xs, w, gs, preferred_element_type=xs.dtype)
     if b is not None:
@@ -232,12 +222,7 @@ def _esfk_any(impl, fused, x1, x2, block_expert, padded_counts, need_db):
         )
         return dw, db
     if impl == "ragged":
-        if _HAS_RAGGED_DN:
-            dw = _ragged_estmm(x1, x2, padded_counts)
-        else:
-            # grouped-transposed ragged dot is inexpressible with plain
-            # lax.ragged_dot; the blocked form computes the same dW
-            dw = _blocked_estmm(x1, x2, block_expert, e)
+        dw = _ragged_estmm(x1, x2, padded_counts)
         db = _ragged_ess(x2, block_expert, e) if need_db else None
         return dw, db
     if impl == "blocked":
@@ -510,13 +495,9 @@ def _esffn_fwd_any(impl, act, glu, x, row_token, row_gate, block_expert,
                    padded_counts, ws, scales=None):
     act_fn = ACTIVATIONS[act]
     if impl == "pallas":
-        if glu:
-            return esffn_glu_pallas(
-                x, row_token, row_gate, block_expert, *ws,
-                w_scales=scales, act=act,
-            )
-        return esffn_mlp_pallas(
-            x, row_token, row_gate, block_expert, *ws,
+        pallas_fn = esffn_glu_pallas if glu else esffn_mlp_pallas
+        return pallas_fn(
+            _gather_rows(x, row_token), row_gate, block_expert, *ws,
             w_scales=scales, act=act,
         )
     if impl == "blocked":

@@ -3,10 +3,9 @@
 Serving keeps one query row per slot and its keys/values scattered across
 fixed-size pages of a shared pool (``models.lm.init_paged_cache``). This
 kernel gathers them page-wise: the grid is (slot, kv-head, logical-page) and
-the K/V BlockSpec index maps read the *scalar-prefetched* page table — the
-same prefetch-driven DMA-gather idiom as the esffn megakernel — so each
-program pulls exactly one physical page into VMEM and folds it into a
-running online softmax. Pages past the slot's length (and, for windowed
+the K/V BlockSpec index maps read the *scalar-prefetched* page table, so
+each program pulls one head of one physical page into VMEM and folds it
+into a running online softmax. Pages past the slot's length (and, for windowed
 layers, pages wholly behind the window) never run; HBM traffic is therefore
 proportional to the tokens actually resident, not to the dense
 ``num_slots x max_seq`` rectangle the old cache allocated up front.
@@ -36,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common import cdiv, pallas_interpret_default, tpu_compiler_params
+from repro.common import cdiv, pallas_interpret_default
 
 NEG_INF = -2.0e38
 
@@ -219,9 +218,9 @@ def _paged_kernel(
     pt_ref,      # scalar prefetch (B, maxp) int32
     len_ref,     # scalar prefetch (B,) int32
     q_ref,       # (1, 1, G, hd)
-    k_ref,       # (1, page, 1, hd) — physical page via pt_ref index map
+    k_ref,       # (1, page, hd) — one head of a physical page (pt_ref map)
     v_ref,
-    *rest,       # [ks (1, page, 1), vs] o_ref, m_s, l_s, acc_s
+    *rest,       # [ks (1, page, Hkv), vs] o_ref, m_s, l_s, acc_s
     scale: float,
     page: int,
     window: Optional[int],
@@ -233,8 +232,15 @@ def _paged_kernel(
     vs_ref = rest.pop(0) if quantized else None
     o_ref, m_s, l_s, acc_s = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
+
+    def head_scale(s_ref):
+        # (page, Hkv) scales of the page -> this head's (page, 1) column.
+        sc = s_ref[0]
+        col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        return jnp.sum(jnp.where(col == h, sc, 0.0), axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -250,10 +256,10 @@ def _paged_kernel(
     @pl.when(run)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)              # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (page, hd)
+        k = k_ref[0].astype(jnp.float32)                 # (page, hd)
         if quantized:
             # per-row dequant of the gathered int8 page (DESIGN.md §8)
-            k = k * ks_ref[0, :, 0][:, None]
+            k = k * head_scale(ks_ref)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -274,9 +280,9 @@ def _paged_kernel(
         p = jnp.exp(logits - m_new)
         l_s[...] = l_s[...] * alpha + p.sum(axis=1, keepdims=True)
         m_s[...] = m_new
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
         if quantized:
-            v = v * vs_ref[0, :, 0][:, None]
+            v = v * head_scale(vs_ref)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -325,18 +331,23 @@ def paged_attention_pallas(
         [maxp * page] * b, page, hq, hkv, hd, k_pool.dtype.itemsize
     )
 
+    # The pools are read as (npages, page, Hkv*hd), a free reshape: one
+    # head's (page, hd) tile is then a block over whole lanes, which the
+    # Mosaic (8, 128) block rule accepts (a (1, hd) window over the
+    # (Hkv, hd) minor dims is refused). Scales come a page at a time.
     kv_spec = pl.BlockSpec(
-        (1, page, 1, hd), lambda bb, h, j, pt, ln: (pt[bb, j], 0, h, 0)
+        (1, page, hd), lambda bb, h, j, pt, ln: (pt[bb, j], 0, h)
     )
     in_specs = [
         pl.BlockSpec((1, 1, g, hd), lambda bb, h, j, pt, ln: (bb, h, 0, 0)),
         kv_spec,
         kv_spec,
     ]
-    args = [qg, k_pool, v_pool]
+    args = [qg, k_pool.reshape(npages, page, hkv * hd),
+            v_pool.reshape(npages, page, hkv * hd)]
     if quantized:
         sc_spec = pl.BlockSpec(
-            (1, page, 1), lambda bb, h, j, pt, ln: (pt[bb, j], 0, h)
+            (1, page, hkv), lambda bb, h, j, pt, ln: (pt[bb, j], 0, 0)
         )
         in_specs += [sc_spec, sc_spec]
         args += [k_scale, v_scale]
@@ -360,7 +371,7 @@ def paged_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -3,8 +3,9 @@ training/decode cells on a host-platform device farm, reporting per-cell
 parallel-config choices, HLO collective counts, and memory estimates."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-# (The two lines above are required verbatim by the multi-pod dry-run spec.)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the device count on first init,
+# and the host-device farm is the CPU backend's (never a chip's).
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -26,6 +27,7 @@ from repro.launch import inputs as inputs_lib               # noqa: E402
 from repro.launch import steps as steps_lib                 # noqa: E402
 from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.optim import adamw                               # noqa: E402
+from repro.parallel.autotune import V5E                     # noqa: E402
 from repro.parallel.sharding import ParallelConfig          # noqa: E402
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
@@ -38,10 +40,10 @@ sharding is coherent (no mismatch, no unsupported collective) and
 numbers feed EXPERIMENTS.md §Roofline.
 """
 
-# v5e hardware model (per chip)
-PEAK_FLOPS = 197e12      # bf16
-HBM_BW = 819e9           # bytes/s
-LINK_BW = 50e9           # bytes/s/link ICI
+# v5e hardware model (per chip), from the one table in parallel.autotune
+PEAK_FLOPS = V5E.peak_flops
+HBM_BW = V5E.hbm_bw
+LINK_BW = V5E.link_bw
 
 COLLECTIVE_RE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"

@@ -40,14 +40,8 @@ def split_model_axis(shape, axes, node_size: int):
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU).
-
-    axis_types= only exists on newer jax; older releases (0.4.3x) default to
-    Auto axes anyway, so fall back to the plain call there."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )

@@ -37,7 +37,7 @@ import numpy as np
 
 from repro import configs as cfglib
 from repro import obs
-from repro.common import cdiv, tree_bytes
+from repro.common import cdiv, enable_compile_cache, tree_bytes
 from repro.core import hetero as hetero_lib
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_mesh, split_model_axis
@@ -1430,4 +1430,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -92,10 +92,17 @@ def chunked_xent(x, params, cfg: ModelConfig, labels, mask,
             per_tok = per_tok.mean(-1)
         return jnp.sum(per_tok * m_c)
 
-    total = jnp.zeros((), jnp.float32)
-    for c in range(n_chunks):
-        sl = slice(c * cs, (c + 1) * cs)
-        total = total + chunk_loss(x[:, sl], labels[:, sl], mask[:, sl])
+    # A scan, not an unrolled loop: its backward accumulates the output
+    # head's gradient in one carry. Unrolled, every chunk kept a float32
+    # head-sized gradient of its own alive (13 GB per chip for a replicated
+    # 151936-row head under data_centric).
+    def body(total, c):
+        def sl(a):
+            return jax.lax.dynamic_slice_in_dim(a, c * cs, cs, axis=1)
+        return total + chunk_loss(sl(x), sl(labels), sl(mask)), None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                            jnp.arange(n_chunks))
     return total / jnp.maximum(jnp.sum(mask), 1.0)
 
 

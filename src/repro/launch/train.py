@@ -33,6 +33,7 @@ import numpy as np
 from repro import configs as cfglib
 from repro import obs
 from repro.checkpoint import manager as ckpt
+from repro.common import enable_compile_cache
 from repro.configs.base import ShapeConfig
 from repro.core import hetero as hetero_lib
 from repro.data.pipeline import DataConfig, Prefetcher, TokenSource
@@ -512,4 +513,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -51,7 +51,9 @@ def schedule(step: jax.Array, cfg: OptimizerConfig) -> jax.Array:
 
 def init_opt_state(params: Any, cfg: OptimizerConfig) -> dict:
     sd = jnp.dtype(cfg.state_dtype)
-    zeros_like = lambda p: jnp.zeros(p.shape, sd)
+    # zeros_like keeps a sharded param's sharding; jnp.zeros(p.shape) would
+    # put every moment whole on the default device.
+    zeros_like = lambda p: jnp.zeros_like(p, dtype=sd)
     state = {
         "m": jax.tree.map(zeros_like, params),
         "v": jax.tree.map(zeros_like, params),

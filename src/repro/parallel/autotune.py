@@ -38,6 +38,7 @@ import dataclasses
 import math
 from typing import Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -87,13 +88,38 @@ class HardwareProfile:
 
     ``topology`` (DESIGN.md §10): optional two-level interconnect; ``None``
     keeps the flat single-bandwidth fabric priced at ``link_bw``."""
-    peak_flops: float = 197e12   # bf16 MXU peak (v5e)
-    hbm_bw: float = 819e9        # HBM bytes/s (v5e)
-    link_bw: float = 50e9        # ICI per-link bytes/s (v5e)
+    peak_flops: float            # bf16 MXU peak
+    hbm_bw: float                # HBM bytes/s
+    link_bw: float               # ICI per-link bytes/s
     topology: Optional[Topology] = None
 
 
-V5E = HardwareProfile()
+#: The one hardware table, keyed by ``jax.Device.device_kind``.
+#: TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s ICI per chip over 4 links (50 GB/s each).
+HARDWARE = {
+    "TPU v5 lite": HardwareProfile(
+        peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+V5E = HARDWARE["TPU v5 lite"]
+
+
+def hardware_profile() -> HardwareProfile:
+    """The profile of the first JAX device.
+
+    A CPU prices the v5e the repo targets (CPU runs rehearse the chip's
+    decisions); any other device kind missing from ``HARDWARE`` is an
+    error, never a default."""
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return V5E
+    try:
+        return HARDWARE[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware profile for device kind {device.device_kind!r}; "
+            f"add it to autotune.HARDWARE with its source") from None
 
 
 def _token_coll_cost(tok_bytes: float, n_dev: float, hw: HardwareProfile) -> float:
@@ -235,11 +261,12 @@ def layer_latency(
     ``n_dev`` may be fractional: heterogeneous groups report an *effective*
     device count (see ``effective_devices``).
     ``fused_ffn``: with the fused expert FFN (kernels.esffn, DESIGN.md §5,
-    the TPU default) inter-stage activations stay in VMEM. Unfused, the
-    HBM term additionally pays the (Np, D) sorted-copy and (Np, F) hidden
-    round-trips between the 3-4 separate kernels — which inflates the
-    token-proportional side of the roofline and moves the data-/model-
-    centric crossover.
+    the TPU default) the (Np, F) hidden activations stay in VMEM. Unfused,
+    the HBM term additionally pays their round-trips between the 3-4
+    separate kernels — which inflates the token-proportional side of the
+    roofline and moves the data-/model-centric crossover. Both paths pay
+    the (Np, D) sorted-copy round-trip: the fused kernel reads rows that
+    one XLA gather wrote before it.
     ``weight_bits`` (DESIGN.md §8): expert-weight storage bits. Quantized
     experts (8) shrink the weight term of BOTH the HBM and the all-gather
     bills while the token bytes stay bf16 — data-centric's constant
@@ -250,24 +277,25 @@ def layer_latency(
     flops = 2 * active_rows * d * f * 2  # two MLPs
     w_bytes = e * 2 * d * f * (weight_bits / 8)  # full expert params
     tok_bytes = tokens * d * 2
-    # Unfused inter-stage HBM round-trips (1 write + 1 read each), bf16:
-    # the expert-sorted (Np, D) copy and the (Np, F) hidden activations.
+    # Inter-stage HBM round-trips (1 write + 1 read each), bf16: the
+    # expert-sorted (Np, D) copy (every path) and the (Np, F) hidden
+    # activations (unfused only).
     srt_bytes = 2 * active_rows * d * 2
     hid_bytes = 2 * active_rows * f * 2
     if mode == "model_centric":
         compute = flops / n_dev / hw.peak_flops   # rows x F/n per device
-        mem = (w_bytes / n_dev + tok_bytes) / hw.hbm_bw
+        # every device holds the whole group's gathered tokens; the hidden
+        # is TP-sharded over F.
+        mem = (w_bytes / n_dev + tok_bytes + srt_bytes) / hw.hbm_bw
         if not fused_ffn:
-            # every device holds the whole group's gathered tokens; the
-            # hidden is TP-sharded over F.
-            mem += (srt_bytes + hid_bytes / n_dev) / hw.hbm_bw
+            mem += hid_bytes / n_dev / hw.hbm_bw
         coll = _token_coll_cost(tok_bytes, n_dev, hw)  # AG tokens + RS outputs
     elif mode == "data_centric":
         compute = flops / n_dev / hw.peak_flops   # tokens/n per device
-        mem = (w_bytes + tok_bytes / n_dev) / hw.hbm_bw
+        # tokens (and therefore both round-trips) are split over devices.
+        mem = (w_bytes + (tok_bytes + srt_bytes) / n_dev) / hw.hbm_bw
         if not fused_ffn:
-            # tokens (and therefore both round-trips) are split over devices.
-            mem += (srt_bytes + hid_bytes) / n_dev / hw.hbm_bw
+            mem += hid_bytes / n_dev / hw.hbm_bw
         coll = _weight_coll_cost(w_bytes, n_dev, hw)  # AG weights
     else:
         raise ValueError(mode)
@@ -339,15 +367,15 @@ def layer_latency_uneven(
         w_bytes = e * 2 * d * f * (bits[i] / 8)
         if mode == "model_centric":
             compute = flops * hid_frac[i] / peak
-            mem = (w_bytes * hid_frac[i] + tok_bytes) / hbm
+            mem = (w_bytes * hid_frac[i] + tok_bytes + srt_bytes) / hbm
             if not fused_ffn:
-                mem += (srt_bytes + hid_bytes * hid_frac[i]) / hbm
+                mem += hid_bytes * hid_frac[i] / hbm
             coll = _token_coll_cost(tok_bytes, n, hw)
         elif mode == "data_centric":
             compute = flops * tok_frac[i] / peak
-            mem = (w_bytes + tok_bytes * tok_frac[i]) / hbm
+            mem = (w_bytes + (tok_bytes + srt_bytes) * tok_frac[i]) / hbm
             if not fused_ffn:
-                mem += (srt_bytes + hid_bytes) * tok_frac[i] / hbm
+                mem += hid_bytes * tok_frac[i] / hbm
             coll = _weight_coll_cost(w_bytes, n, hw)
         else:
             raise ValueError(mode)
@@ -640,7 +668,9 @@ def resolve_layer_mode(
     from repro.quant.core import quant_bits
 
     topo = getattr(cfg, "topology", None)
-    hw = V5E if topo is None else dataclasses.replace(V5E, topology=topo)
+    hw = hardware_profile()
+    if topo is not None:
+        hw = dataclasses.replace(hw, topology=topo)
     n_dev = float(_tp_group_size(cfg, mesh))
     fused = getattr(cfg, "fused_ffn", None)
     bits = quant_bits(getattr(cfg, "quant", "none"))

@@ -79,32 +79,10 @@ class MoEStatic(NamedTuple):
     softmax_after_topk: bool = False
 
 
-def _resolve_shard_map():
-    """jax.shard_map moved out of jax.experimental in 2025-era jax; the
-    replication check was renamed check_rep -> check_vma along the way (some
-    releases expose jax.shard_map but still spell it check_rep). Resolve
-    both once at import so the mesh path runs across the 0.4.x-0.6.x span."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        kw = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-              else "check_rep")
-    except (TypeError, ValueError):  # C-level signature: assume modern name
-        kw = "check_vma"
-    return sm, kw
-
-
-_SHARD_MAP, _SHARD_MAP_CHECK_KW = _resolve_shard_map()
-
-
 def _shard_map(body, mesh, in_specs, out_specs):
-    return _SHARD_MAP(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
 
 

@@ -166,6 +166,18 @@ def scale_block_dims(wdims, sdims, bdims) -> tuple:
     return tuple(out)
 
 
+def tile_scales(scales: jax.Array, wdims, bdims) -> jax.Array:
+    """Lay a weight's ``(E, nA, nB)`` scales out as ``(E, nA/sa, nB/sb, sa,
+    sb)``, one ``(sa, sb)`` tile per ``(A, B)`` kernel block of the weight.
+
+    The kernels index that operand with a ``(1, 1, 1, sa, sb)`` block: its
+    last two dims are whole, which the Mosaic (8, 128) block rule accepts
+    where a ``(1, sa, sb)`` window of the plain scales is refused."""
+    sa, sb = scale_block_dims(wdims, scales.shape[-2:], bdims)
+    e, na, nb = scales.shape
+    return scales.reshape(e, na // sa, sa, nb // sb, sb).transpose(0, 1, 3, 2, 4)
+
+
 def dequant_tile(w: jax.Array, s: jax.Array) -> jax.Array:
     """In-kernel VMEM dequant of one 2-D weight tile (DESIGN.md §8).
 
@@ -176,8 +188,13 @@ def dequant_tile(w: jax.Array, s: jax.Array) -> jax.Array:
     """
     a, b = w.shape
     na, nb = s.shape
-    wf = w.astype(jnp.float32).reshape(na, a // na, nb, b // nb)
-    return (wf * s.astype(jnp.float32)[:, None, :, None]).reshape(a, b)
+    wf = w.astype(jnp.float32)
+    s = s.astype(jnp.float32)
+    if nb > 1:  # widen the scales along lanes: (na, nb) -> (na, B)
+        s = jnp.broadcast_to(s[:, :, None], (na, nb, b // nb)).reshape(na, b)
+    if na > 1:  # then along sublanes: (na, B) -> (A, B)
+        s = jnp.broadcast_to(s[:, None, :], (na, a // na, b)).reshape(a, b)
+    return wf * s
 
 
 # ---------------------------------------------------------------------------

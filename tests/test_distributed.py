@@ -126,20 +126,25 @@ def run(mesh):
         params = jax.tree.map(jax.device_put, params,
                               tree_shardings(params, specs, pcfg, mesh))
     opt = adamw.init_opt_state(params, opt_cfg)
+    # Moments live where their params live, not whole on one device.
+    placed = all(m.sharding == p.sharding for m, p in zip(
+        jax.tree.leaves(opt["m"]), jax.tree.leaves(params)))
     step = jax.jit(steps_lib.make_train_step(cfg, pcfg, mesh, opt_cfg,
                                              (B, S, cfg.d_model)))
     losses = []
     for i in range(3):
         params, opt, m = step(params, opt, batch)
         losses.append(float(m["loss"]))
-    return losses
+    return losses, placed
 
 mesh = make_mesh((4, 2), ("data", "model"))
 with mesh:
-    dist = run(mesh)
-single = run(None)
-print("RESULT" + json.dumps({"dist": dist, "single": single}))
+    dist, placed = run(mesh)
+single, _ = run(None)
+print("RESULT" + json.dumps({"dist": dist, "single": single,
+                             "placed": placed}))
 """)
+    assert out["placed"], "optimizer moments not sharded like their params"
     for a, b in zip(out["dist"], out["single"]):
         assert abs(a - b) < 2e-3, (out["dist"], out["single"])
 

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core import espec
-from repro.core.reindex import build_reindex
+from repro.core.reindex import build_reindex, gather_rows
 from repro.kernels import ops
 from repro.kernels.esffn import esffn_cost, esffn_glu_pallas, esffn_mlp_pallas
 
@@ -165,7 +165,8 @@ def test_pallas_kernel_direct_bf16():
     wu = w["w_up"].astype(jnp.bfloat16)
     wd = w["w_down"].astype(jnp.bfloat16)
     got = esffn_glu_pallas(
-        x, ri.row_token, ri.row_gate, ri.block_expert, wg, wu, wd, act="silu"
+        gather_rows(x, ri.row_token), ri.row_gate, ri.block_expert,
+        wg, wu, wd, act="silu",
     )
     assert got.dtype == jnp.bfloat16
     want = ops.esffn_glu(
@@ -184,7 +185,7 @@ def test_pallas_kernel_hidden_blocking():
     w = _weights()
     x = _x()
     got = esffn_mlp_pallas(
-        x, ri.row_token, ri.row_gate, ri.block_expert,
+        gather_rows(x, ri.row_token), ri.row_gate, ri.block_expert,
         w["w1"], w["b1"], w["w2"], w["b2"], act="gelu", bf=8,
     )
     want = _run(x, ri, w, glu=False, bias=True, impl="blocked", fused=False)
@@ -248,3 +249,34 @@ def test_autotune_unfused_bytes_shift_crossover():
     cu = autotune.crossover_tokens(d, f, e, k, n_dev=16, fused_ffn=False)
     assert cf is not None and cu is not None
     assert cu >= cf
+
+
+@pytest.mark.parametrize("uneven", [False, True])
+@pytest.mark.parametrize("mode", ["model_centric", "data_centric"])
+def test_autotune_fused_prices_sorted_copy(mode, uneven):
+    """The fused FFN still reads the (Np, D) rows one XLA gather wrote, so
+    the roofline prices it as the unfused composition minus ONLY the
+    (Np, F) hidden round-trip — and above the sorted-copy-free bill."""
+    from repro.parallel import autotune
+
+    # Memory-bound: compute and links far faster than HBM.
+    hw = autotune.HardwareProfile(peak_flops=1e30, hbm_bw=1e12, link_bw=1e30)
+    tokens, d, f, e, k, n = 4096, 1024, 4096, 8, 2, 4
+    rows = tokens * k
+    srt, hid = 2 * rows * d * 2, 2 * rows * f * 2
+    tok, w = tokens * d * 2, e * 2 * d * f * 2
+
+    def lat(fused):
+        if uneven:
+            return autotune.layer_latency_uneven(
+                mode, tokens, d, f, e, k, [1.0] * n, hw=hw, fused_ffn=fused)
+        return autotune.layer_latency(mode, tokens, d, f, e, k, n, hw,
+                                      fused_ffn=fused)
+
+    if mode == "model_centric":
+        want = (w / n + tok + srt) / hw.hbm_bw
+    else:
+        want = (w + (tok + srt) / n) / hw.hbm_bw
+    np.testing.assert_allclose(lat(True), want, rtol=1e-12)
+    np.testing.assert_allclose(lat(False) - lat(True), hid / n / hw.hbm_bw,
+                               rtol=1e-9)

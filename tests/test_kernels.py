@@ -116,3 +116,43 @@ def test_esfk_empty_expert_grads_zero():
     assert np.abs(np.asarray(dw[1:])).max() == 0.0
     assert np.abs(np.asarray(db[1:])).max() == 0.0
     assert np.abs(np.asarray(dw[0])).max() > 0.0
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_pallas_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode is the CPU's path alone; a backend with no Mosaic
+    lowering is an error, never a silent interpreter."""
+    from repro import common
+
+    monkeypatch.setattr(common.jax, "default_backend", lambda: backend)
+    common.pallas_interpret_default.cache_clear()
+    try:
+        if interpret is None:
+            with pytest.raises(RuntimeError, match="cannot run"):
+                common.pallas_interpret_default()
+        else:
+            assert common.pallas_interpret_default() is interpret
+    finally:
+        common.pallas_interpret_default.cache_clear()
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set; otherwise the
+    cache is pinned to ``<repo>/.jax_cache``. (The config update is
+    recorded, not applied: tests keep the cache off.)"""
+    from repro import common
+
+    calls = []
+    monkeypatch.setattr(common.jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = common.enable_compile_cache()
+        assert path == str(common.REPO_ROOT / ".jax_cache")
+        assert calls == [("jax_compilation_cache_dir", path)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+        assert common.enable_compile_cache() == str(tmp_path / env)
+        assert calls == []

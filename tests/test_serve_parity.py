@@ -574,13 +574,24 @@ def test_prefill_chunk_size_is_invisible():
 # --- speculative decoding rows (ISSUE 9, DESIGN.md §11) ------------------
 
 class _WrongDrafter:
-    """Adversarial drafter proposing deliberately wrong tokens — every
-    verify round hits a mid-verify rejection and the rollback path, yet
-    the committed stream must be byte-identical (the sampled correction
-    token IS the non-speculative token)."""
+    """Adversarial drafter proposing provably wrong tokens — every verify
+    round hits a mid-verify rejection and the rollback path, yet the
+    committed stream must be byte-identical (the sampled correction token
+    IS the non-speculative token).
+
+    It knows each request's non-speculative stream (prompt + output), so
+    its first draft is the true next token plus one (mod vocab): never
+    accepted, hence no later draft of the round is either."""
+
+    def __init__(self, streams: dict, vocab: int):
+        self.streams = streams
+        self.vocab = vocab
 
     def draft(self, history, k, rid=-1):
-        return [(int(history[-1]) + 1 + j) % 7 for j in range(k)]
+        stream = self.streams[rid]
+        pos = len(history)
+        nxt = int(stream[pos]) if pos < len(stream) else 0
+        return [(nxt + 1 + j) % self.vocab for j in range(k)]
 
 
 def _spec_requests(cfg, n, seed):
@@ -628,8 +639,10 @@ def test_spec_forced_midverify_rejection_parity():
     reqs = _spec_requests(cfg, N_REQ, seed=53)
 
     _, out_off = _run_paged(cfg, pcfg, params, reqs)
+    streams = {r.rid: list(r.prompt) + out_off[r.rid] for r in reqs}
     srv_on, out_on = _run_paged(cfg, pcfg, params, reqs,
-                                spec=_WrongDrafter(), audit=True)
+                                spec=_WrongDrafter(streams, cfg.vocab_size),
+                                audit=True)
     assert out_on == out_off
     sp = srv_on.spec.stats()
     assert sp["drafted"] > 0 and sp["accepted_drafts"] == 0

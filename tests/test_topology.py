@@ -239,3 +239,25 @@ if HAVE_HYPOTHESIS:
     def test_dispatch_bytes_property(case):
         d, _, _, k, n_dev, node_size, tokens = case
         check_dispatch_bytes(tokens, d, k, n_dev, node_size)
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,known", [
+    ("cpu", "cpu", True),            # CPU runs price the v5e they rehearse
+    ("tpu", "TPU v5 lite", True),
+    ("tpu", "TPU v9 imaginary", False),
+    ("gpu", "NVIDIA H100", False),
+])
+def test_hardware_profile_keyed_by_device_kind(monkeypatch, platform, kind,
+                                               known):
+    monkeypatch.setattr(at.jax, "devices",
+                        lambda: [_FakeDevice(platform, kind)])
+    if known:
+        assert at.hardware_profile() is at.V5E
+    else:
+        with pytest.raises(ValueError, match="no hardware profile"):
+            at.hardware_profile()
