@@ -17,6 +17,11 @@ only the final scatter-add combine remains outside.
 Two expert body types are supported:
   * ``moe_mlp`` — the paper's 2-MLP expert (Swin-MoE, classic GShard FFN).
   * ``moe_glu`` — gate/up/down GLU experts (Mixtral / Qwen3 / Jamba).
+
+Both lower their parts under layer scopes (``jax.named_scope``), which the
+HLO ops keep in their ``op_name`` metadata, forward and backward:
+``moe.experts`` (the expert FFN with its row gather) and ``moe.combine``;
+the callers route under ``moe.route``.
 """
 from __future__ import annotations
 
@@ -61,20 +66,25 @@ def moe_mlp(
     if fused is None:
         fused = ops.default_fused_ffn(impl)
     if fused:
-        ys = ops.esffn_mlp(
-            x, ri.row_token, ri.row_gate, ri.block_expert, ri.padded_counts,
-            w1, b1, w2, b2, scales=scales, act=act, impl=impl,
-        )
-        return scatter_rows(ys, ri.row_token, x.shape[0])
+        with jax.named_scope("moe.experts"):
+            ys = ops.esffn_mlp(
+                x, ri.row_token, ri.row_gate, ri.block_expert,
+                ri.padded_counts, w1, b1, w2, b2, scales=scales, act=act,
+                impl=impl,
+            )
+        with jax.named_scope("moe.combine"):
+            return scatter_rows(ys, ri.row_token, x.shape[0])
     f = ACTIVATIONS[act]
     s1, s2 = scales if scales is not None else (None, None)
-    xs = gather_sorted(x, ri)
-    h = ops.esmm(xs, w1, b1, ri.block_expert, ri.padded_counts, impl=impl,
-                 w_scales=s1)
-    h = f(h)
-    ys = ops.esmm(h, w2, b2, ri.block_expert, ri.padded_counts, impl=impl,
-                  w_scales=s2)
-    return combine_scatter(ys, ri, x.shape[0])
+    with jax.named_scope("moe.experts"):
+        xs = gather_sorted(x, ri)
+        h = ops.esmm(xs, w1, b1, ri.block_expert, ri.padded_counts,
+                     impl=impl, w_scales=s1)
+        h = f(h)
+        ys = ops.esmm(h, w2, b2, ri.block_expert, ri.padded_counts,
+                      impl=impl, w_scales=s2)
+    with jax.named_scope("moe.combine"):
+        return combine_scatter(ys, ri, x.shape[0])
 
 
 def moe_glu(
@@ -97,22 +107,27 @@ def moe_glu(
     if fused is None:
         fused = ops.default_fused_ffn(impl)
     if fused:
-        ys = ops.esffn_glu(
-            x, ri.row_token, ri.row_gate, ri.block_expert, ri.padded_counts,
-            w_gate, w_up, w_down, scales=scales, act=act, impl=impl,
-        )
-        return scatter_rows(ys, ri.row_token, x.shape[0])
+        with jax.named_scope("moe.experts"):
+            ys = ops.esffn_glu(
+                x, ri.row_token, ri.row_gate, ri.block_expert,
+                ri.padded_counts, w_gate, w_up, w_down, scales=scales,
+                act=act, impl=impl,
+            )
+        with jax.named_scope("moe.combine"):
+            return scatter_rows(ys, ri.row_token, x.shape[0])
     f = ACTIVATIONS[act]
     sg, su, sd = scales if scales is not None else (None, None, None)
-    xs = gather_sorted(x, ri)
-    g = ops.esmm(xs, w_gate, None, ri.block_expert, ri.padded_counts,
-                 impl=impl, w_scales=sg)
-    u = ops.esmm(xs, w_up, None, ri.block_expert, ri.padded_counts,
-                 impl=impl, w_scales=su)
-    h = f(g) * u
-    ys = ops.esmm(h, w_down, None, ri.block_expert, ri.padded_counts,
-                  impl=impl, w_scales=sd)
-    return combine_scatter(ys, ri, x.shape[0])
+    with jax.named_scope("moe.experts"):
+        xs = gather_sorted(x, ri)
+        g = ops.esmm(xs, w_gate, None, ri.block_expert, ri.padded_counts,
+                     impl=impl, w_scales=sg)
+        u = ops.esmm(xs, w_up, None, ri.block_expert, ri.padded_counts,
+                     impl=impl, w_scales=su)
+        h = f(g) * u
+        ys = ops.esmm(h, w_down, None, ri.block_expert, ri.padded_counts,
+                      impl=impl, w_scales=sd)
+    with jax.named_scope("moe.combine"):
+        return combine_scatter(ys, ri, x.shape[0])
 
 
 class MoEOutput(NamedTuple):

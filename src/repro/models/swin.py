@@ -12,6 +12,12 @@ exactly the formulation of Fig. 3 — through any of the execution paths:
 Simplification vs. the reference Swin: shifted windows are implemented by
 rolling without the cross-window attention mask (systems-benchmark fidelity:
 identical FLOPs/memory/communication, slightly different masking semantics).
+
+Each part of a block lowers under a layer scope (``jax.named_scope``):
+``swin.attention`` (the shift rolls and window attention), ``swin.norm``
+(every LayerNorm) and ``swin.mlp`` (the dense MLP). A scope changes the
+``op_name`` metadata of the HLO ops it covers, forward and backward, and
+not what they compute, so a device trace can attribute each op to its layer.
 """
 from __future__ import annotations
 
@@ -168,10 +174,12 @@ def init_swin(key, cfg: SwinConfig):
 # ---------------------------------------------------------------------------
 
 def _ln(p, x, eps):
-    xf = x.astype(jnp.float32)
-    mu = xf.mean(-1, keepdims=True)
-    var = xf.var(-1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]).astype(x.dtype)
+    with jax.named_scope("swin.norm"):
+        xf = x.astype(jnp.float32)
+        mu = xf.mean(-1, keepdims=True)
+        var = xf.var(-1, keepdims=True)
+        return ((xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"]
+                + p["bias"]).astype(x.dtype)
 
 
 def _rel_bias_index(window):
@@ -269,11 +277,13 @@ def swin_forward(
             w_eff = min(cfg.window, x.shape[1], x.shape[2])
             shift = (w_eff // 2) if (bidx % 2 == 1 and w_eff < x.shape[1]) else 0
             h = _ln(blk["ln1"], x, cfg.norm_eps)
-            if shift:
-                h = jnp.roll(h, (-shift, -shift), axis=(1, 2))
-            h = _window_attention(blk["attn"], h, heads, cfg.window, cfg.norm_eps)
-            if shift:
-                h = jnp.roll(h, (shift, shift), axis=(1, 2))
+            with jax.named_scope("swin.attention"):
+                if shift:
+                    h = jnp.roll(h, (-shift, -shift), axis=(1, 2))
+                h = _window_attention(blk["attn"], h, heads, cfg.window,
+                                      cfg.norm_eps)
+                if shift:
+                    h = jnp.roll(h, (shift, shift), axis=(1, 2))
             x = x + h
             h = _ln(blk["ln2"], x, cfg.norm_eps)
             bb, hh, ww, cc = h.shape
@@ -288,9 +298,10 @@ def swin_forward(
                 n_moe += 1
             else:
                 m = blk["mlp"]
-                y = jax.nn.gelu(
-                    h @ m["w1"].astype(dtype) + m["b1"].astype(dtype)
-                ) @ m["w2"].astype(dtype) + m["b2"].astype(dtype)
+                with jax.named_scope("swin.mlp"):
+                    y = jax.nn.gelu(
+                        h @ m["w1"].astype(dtype) + m["b1"].astype(dtype)
+                    ) @ m["w2"].astype(dtype) + m["b2"].astype(dtype)
             x = x + y
         if "merge_w" in stage:
             bb, hh, ww, cc = x.shape

@@ -78,10 +78,12 @@ def global_norm(tree: Any) -> jax.Array:
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
+@jax.named_scope("optim.adamw")
 def apply_updates(
     params: Any, grads: Any, state: dict, cfg: OptimizerConfig
 ) -> tuple[Any, dict, dict]:
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics). Every op of the update
+    runs under the layer scope ``optim.adamw``."""
     step = state["step"] + 1
     lr = schedule(step, cfg)
     gnorm = global_norm(grads)

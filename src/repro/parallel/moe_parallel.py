@@ -204,20 +204,22 @@ def hexa_moe_island(
         if token_valid is not None:
             token_valid = _ag_hier(token_valid, tp, 0, hier)
 
-    r = route(
-        x, p.router, ms.top_k,
-        norm_topk=ms.norm_topk,
-        softmax_after_topk=ms.softmax_after_topk,
-        noise_rng=noise_rng,
-        valid_mask=token_valid,
-    )
-    # Router telemetry (DESIGN.md §12): device-side accumulators over the
-    # rows this device routed; the caller de-duplicates TP-replicated
-    # counts before they leave the shard_map.
-    stats = (obs_device.expert_stats(r.expert_idx, r.probs, ms.num_experts,
-                                     valid_mask=token_valid)
-             if cfg.collect_router_stats else None)
-    ri = build_reindex(r.expert_idx, r.gates, ms.num_experts, cfg.blk)
+    with jax.named_scope("moe.route"):
+        r = route(
+            x, p.router, ms.top_k,
+            norm_topk=ms.norm_topk,
+            softmax_after_topk=ms.softmax_after_topk,
+            noise_rng=noise_rng,
+            valid_mask=token_valid,
+        )
+        # Router telemetry (DESIGN.md §12): device-side accumulators over
+        # the rows this device routed; the caller de-duplicates
+        # TP-replicated counts before they leave the shard_map.
+        stats = (obs_device.expert_stats(r.expert_idx, r.probs,
+                                         ms.num_experts,
+                                         valid_mask=token_valid)
+                 if cfg.collect_router_stats else None)
+        ri = build_reindex(r.expert_idx, r.gates, ms.num_experts, cfg.blk)
 
     # True-quantized expert weights (int8/fp8 payloads + block scales,
     # DESIGN.md §8): the scales are NOT sharded congruently with a
@@ -339,13 +341,14 @@ def ep_moe_island(
     e, k = ms.num_experts, ms.top_k
     assert e % max(ep, 1) == 0, "EP baseline needs num_experts % ep == 0"
 
-    r = route(
-        x, p.router, k,
-        norm_topk=ms.norm_topk,
-        softmax_after_topk=ms.softmax_after_topk,
-        noise_rng=noise_rng,
-        valid_mask=token_valid,
-    )
+    with jax.named_scope("moe.route"):
+        r = route(
+            x, p.router, k,
+            norm_topk=ms.norm_topk,
+            softmax_after_topk=ms.softmax_after_topk,
+            noise_rng=noise_rng,
+            valid_mask=token_valid,
+        )
     n, d = x.shape
     capacity = max(int((n * k / e) * cfg.capacity_factor), 1)
 
