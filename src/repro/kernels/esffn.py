@@ -21,10 +21,12 @@ Per BLK_M block the kernel
      scatter-add needs no further gate multiply).
 
 The ``(Np, F)`` hidden activations exist only tile-wise in VMEM, so the
-kernel's HBM traffic is the token rows, one expert weight tile per block,
-and the output — the forward analogue of the ESFK backward fusion
-(DESIGN.md §2), and the dominant inter-stage traffic the unfused
-gather/esmm/act/esmm/combine composition round-trips through HBM.
+kernel's HBM traffic is the token rows, the expert weights and the output
+— the forward analogue of the ESFK backward fusion (DESIGN.md §2): the
+unfused gather/esmm/act/esmm/combine composition round-trips the hidden
+through HBM between stages. The hidden block defaults to ``esffn_blocks``:
+all of F where the expert's weights fit VMEM whole, so they are fetched
+once per run of the expert's consecutive row blocks, not once per step.
 
 Padding rows are the zero rows ``gather_rows`` gives sentinel tokens;
 whatever they compute (a bias, at most) is annihilated by their zero
@@ -37,6 +39,7 @@ ESMM/ESFK ops.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import ACTIVATIONS, pallas_interpret_default
+from repro.kernels import tiles
 from repro.quant.core import dequant_tile, tile_scales
 
 _CONTRACT_K = (((1,), (0,)), ((), ()))  # row-major matmul: (m,k)x(k,n)
@@ -92,6 +96,54 @@ def esffn_cost(
         flops=flops, bytes_accessed=int(bytes_accessed),
         transcendentals=np_rows * f,
     )
+
+
+def esffn_blocks(
+    bm: int,
+    d: int,
+    f: int,
+    *,
+    glu: bool,
+    x_itemsize: int,
+    w_itemsize: int,
+    quant_tiles: tuple[int, int] | None = None,
+    has_b1: bool = False,
+    has_b2: bool = False,
+) -> int:
+    """``bf``, the hidden block of an esffn call with row block ``bm`` at
+    width ``d`` and hidden ``f``: all of ``f`` where the expert's whole
+    weights fit the VMEM budget (module ``tiles``), so they stay resident over
+    its row blocks. ``quant_tiles``: a quantized weight's tile along d and
+    f; the one along f must divide ``bf``."""
+    (bf,) = tiles.choose(
+        (f,),
+        lambda b: esffn_vmem(bm, d, *b, glu=glu, x_itemsize=x_itemsize,
+                             w_itemsize=w_itemsize, quant_tiles=quant_tiles,
+                             has_b1=has_b1, has_b2=has_b2),
+        tiles=None if quant_tiles is None else quant_tiles[1:])
+    return bf
+
+
+def esffn_vmem(bm, d, bf, *, glu, x_itemsize, w_itemsize, quant_tiles=None,
+               has_b1=False, has_b2=False) -> int:
+    """VMEM footprint of one esffn call at blocks ``(bm, bf)``."""
+    f32 = 4
+    n_w = 3 if glu else 2
+    blocks = [((bm, d), x_itemsize), ((bm, 1), f32), ((bm, d), x_itemsize)]
+    blocks += [((d, bf), w_itemsize)] * (n_w - 1) + [((bf, d), w_itemsize)]
+    if has_b1:
+        blocks.append(((1, bf), x_itemsize))
+    if has_b2:
+        blocks.append(((1, d), x_itemsize))
+    # The accumulator, the down dot's result and the hidden tiles (g, u,
+    # h); a quantized tile is widened to f32 beside its broadcast scales.
+    scratch = [((bm, d), f32)] * 2 + [((bm, bf), f32)] * n_w
+    if quant_tiles is not None:
+        td, tf = quant_tiles
+        blocks += [((d // td, bf // tf), f32)] * (n_w - 1)
+        blocks.append(((bf // tf, d // td), f32))
+        scratch += [((d, bf), f32)] * (2 * n_w)
+    return tiles.vmem_bytes(blocks, scratch)
 
 
 def _wtile(w_ref, s_ref):
@@ -203,12 +255,10 @@ def _esffn_mlp_kernel(
 
 
 def _call(kernel, xs, row_gate, block_expert, tensor_args,
-          tensor_specs, f_dim, bf, cost, interpret):
+          tensor_specs, f_dim, bf, vmem, cost, interpret):
     np_rows, d = xs.shape
     nm = block_expert.shape[0]
-    assert np_rows % nm == 0, (np_rows, nm)
     bm = np_rows // nm
-    bf = min(bf, f_dim)
     assert f_dim % bf == 0, (f_dim, bf)
     grid = (nm, f_dim // bf)
 
@@ -227,11 +277,33 @@ def _call(kernel, xs, row_gate, block_expert, tensor_args,
         out_shape=jax.ShapeDtypeStruct((np_rows, d), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=tiles.vmem_limit(vmem),
         ),
         cost_estimate=cost,
         interpret=interpret,
     )(block_expert, xs, *tensor_args,
       row_gate.reshape(np_rows, 1).astype(jnp.float32))
+
+
+def _resolve_bf(bf, xs, block_expert, w_up, w_scales, *, glu,
+                has_b1=False, has_b2=False):
+    """The hidden block (``esffn_blocks`` unless given) and the call's
+    VMEM footprint at it."""
+    np_rows, d = xs.shape
+    f = w_up.shape[2]
+    nm = block_expert.shape[0]
+    assert np_rows % nm == 0, (np_rows, nm)
+    quant_tiles = None
+    if w_scales is not None:
+        s_up, s_down = w_scales[0], w_scales[-1]
+        quant_tiles = (math.lcm(d // s_up.shape[1], d // s_down.shape[2]),
+                       math.lcm(f // s_up.shape[2], f // s_down.shape[1]))
+    kw = dict(glu=glu, x_itemsize=xs.dtype.itemsize,
+              w_itemsize=w_up.dtype.itemsize, quant_tiles=quant_tiles,
+              has_b1=has_b1, has_b2=has_b2)
+    bm = np_rows // nm
+    bf = min(bf or esffn_blocks(bm, d, f, **kw), f)
+    return bf, esffn_vmem(bm, d, bf, **kw)
 
 
 def _scale_arg(sc, wdims, bdims, index_map):
@@ -253,7 +325,7 @@ def esffn_glu_pallas(
     *,
     w_scales=None,
     act: str = "silu",
-    bf: int = 128,
+    bf: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused GLU expert FFN: (Np, D) sorted rows -> (Np, D) gate-weighted
@@ -272,7 +344,8 @@ def esffn_glu_pallas(
     e, dw, f = w_gate.shape
     assert dw == d and w_up.shape == (e, d, f) and w_down.shape == (e, f, d)
     nm = block_expert.shape[0]
-    bf_r = min(bf, f)
+    bf_r, vmem = _resolve_bf(bf, xs, block_expert, w_gate, w_scales,
+                             glu=True)
     quantized = w_scales is not None
     kernel = functools.partial(
         _esffn_glu_kernel, act_fn=ACTIVATIONS[act], quantized=quantized
@@ -299,7 +372,7 @@ def esffn_glu_pallas(
         weight_bits=8 * w_gate.dtype.itemsize,
     )
     return _call(kernel, xs, row_gate, block_expert,
-                 args, specs, f, bf, cost, interpret)
+                 args, specs, f, bf_r, vmem, cost, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "bf", "interpret"))
@@ -314,7 +387,7 @@ def esffn_mlp_pallas(
     *,
     w_scales=None,
     act: str = "gelu",
-    bf: int = 128,
+    bf: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused 2-MLP expert FFN (optionally biased); see ``esffn_glu_pallas``.
@@ -329,7 +402,8 @@ def esffn_mlp_pallas(
     e, dw, f = w1.shape
     assert dw == d and w2.shape == (e, f, d)
     nm = block_expert.shape[0]
-    bf_r = min(bf, f)
+    bf_r, vmem = _resolve_bf(bf, xs, block_expert, w1, w_scales, glu=False,
+                             has_b1=b1 is not None, has_b2=b2 is not None)
     quantized = w_scales is not None
     kernel = functools.partial(
         _esffn_mlp_kernel, act_fn=ACTIVATIONS[act],
@@ -365,4 +439,4 @@ def esffn_mlp_pallas(
         weight_bits=8 * w1.dtype.itemsize,
     )
     return _call(kernel, xs, row_gate, block_expert,
-                 args, specs, f, bf, cost, interpret)
+                 args, specs, f, bf_r, vmem, cost, interpret)

@@ -13,7 +13,10 @@ dX remains a separate ESMM (different output layout, MXU-bound anyway).
 
 Grid is (d1_blocks, d2_blocks, m_blocks) with m innermost so that revisits of
 the accumulator output block (one per expert) are consecutive — the sorted
-layout guarantees equal experts occupy consecutive m blocks.
+layout guarantees equal experts occupy consecutive m blocks. The dW blocks
+default to ``esfk_blocks``: the whole ``(D1, D2)`` expert where it fits
+VMEM, so the grid is ``(1, 1, m_blocks)``, x1 and x2 are each read once and
+each expert's dW is written back once.
 
 The db output carries one junk row (shape (E+1, 1, D2), the unit axis
 keeping its (1, 1, BLK_D2) tile within the (8, 128) block rule): for
@@ -31,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import pallas_interpret_default
+from repro.kernels import tiles
 
 
 def _esfk_kernel(
@@ -78,6 +82,34 @@ def _esfk_kernel(
         db_ref[0] = acc_db[...].astype(db_ref.dtype)
 
 
+def esfk_blocks(
+    bm: int,
+    d1: int,
+    d2: int,
+    *,
+    itemsize1: int,
+    itemsize2: int,
+) -> tuple[int, int]:
+    """``(b1, b2)`` of an esfk call with row block ``bm`` over x1
+    ``(Np, d1)`` and x2 ``(Np, d2)``: the whole expert's dW, ``(d1, d2)``,
+    where it fits the VMEM budget (module ``tiles``)."""
+    return tiles.choose(
+        (d1, d2),
+        lambda b: esfk_vmem(bm, *b, itemsize1=itemsize1,
+                            itemsize2=itemsize2))
+
+
+def esfk_vmem(bm, b1, b2, *, itemsize1, itemsize2) -> int:
+    """VMEM footprint of one esfk call at blocks ``(bm, b1, b2)``."""
+    f32 = 4
+    blocks = [((bm, b1), itemsize1), ((bm, b2), itemsize2),
+              ((b1, b2), f32), ((1, b2), f32)]
+    # The two accumulators, the dot's result and x2 widened for db.
+    scratch = [((b1, b2), f32), ((1, b2), f32), ((b1, b2), f32),
+               ((bm, b2), f32)]
+    return tiles.vmem_bytes(blocks, scratch)
+
+
 @functools.partial(
     jax.jit, static_argnames=("bm", "b1", "b2", "interpret")
 )
@@ -89,8 +121,8 @@ def esfk_pallas(
     num_experts: int | None = None,
     *,
     bm: int = 128,
-    b1: int = 128,
-    b2: int = 128,
+    b1: int | None = None,
+    b2: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused (dW, db) over the sorted layout.
@@ -108,8 +140,10 @@ def esfk_pallas(
     assert np_rows == np2
     e = counts.shape[0] if num_experts is None else num_experts
     bm = min(bm, np_rows)
-    b1 = min(b1, d1)
-    b2 = min(b2, d2)
+    i1, i2 = x1.dtype.itemsize, x2.dtype.itemsize
+    auto_b1, auto_b2 = esfk_blocks(bm, d1, d2, itemsize1=i1, itemsize2=i2)
+    b1 = min(b1 or auto_b1, d1)
+    b2 = min(b2 or auto_b2, d2)
     assert np_rows % bm == 0 and d1 % b1 == 0 and d2 % b2 == 0
     assert block_expert.shape[0] * bm == np_rows
     grid = (d1 // b1, d2 // b2, np_rows // bm)
@@ -120,6 +154,7 @@ def esfk_pallas(
         + (d1 // b1) * x2.size * x2.dtype.itemsize
         + e * d1 * d2 * 4
     )
+    vmem = esfk_vmem(bm, b1, b2, itemsize1=i1, itemsize2=i2)
 
     dw, db_full = pl.pallas_call(
         _esfk_kernel,
@@ -151,6 +186,7 @@ def esfk_pallas(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=tiles.vmem_limit(vmem),
         ),
         cost_estimate=pl.CostEstimate(
             flops=flops, bytes_accessed=bytes_accessed, transcendentals=0

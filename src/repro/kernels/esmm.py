@@ -2,7 +2,10 @@
 
 Grouped matmul over the expert-sorted layout: every BLK_M-row block of ``xs``
 belongs to one expert (``block_expert``, scalar-prefetched so Mosaic can
-schedule the weight DMA for block i+1 while block i is on the MXU).
+schedule the weight DMA for block i+1 while block i is on the MXU). The
+weight block defaults to the whole expert where it fits VMEM
+(``esmm_blocks``): its index then holds over the expert's consecutive row
+blocks and the weights are fetched once per run of them.
 
   ys[i] = xs[i] @ W[e(i)] (+ b[e(i)])          (paper Fig. 4(b))
 
@@ -29,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import pallas_interpret_default
+from repro.kernels import tiles
 from repro.quant.core import dequant_tile, tile_scales
 
 
@@ -73,6 +77,45 @@ def _esmm_kernel(
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def esmm_blocks(
+    bm: int,
+    d1: int,
+    d2: int,
+    *,
+    x_itemsize: int,
+    w_itemsize: int,
+    quant_tiles: tuple[int, int] | None = None,
+    has_bias: bool = False,
+) -> tuple[int, int]:
+    """``(bk, bn)`` of an esmm call with row block ``bm`` over
+    ``(Np, d1) x (d1, d2)``: the whole expert, ``(d1, d2)``, where it fits
+    the VMEM budget (module ``tiles``). ``quant_tiles``: a quantized weight's
+    tile along d1 and d2, which must divide the blocks."""
+    return tiles.choose(
+        (d1, d2),
+        lambda b: esmm_vmem(bm, *b, x_itemsize=x_itemsize,
+                            w_itemsize=w_itemsize, quant_tiles=quant_tiles,
+                            has_bias=has_bias),
+        tiles=quant_tiles)
+
+
+def esmm_vmem(bm, bk, bn, *, x_itemsize, w_itemsize, quant_tiles=None,
+              has_bias=False) -> int:
+    """VMEM footprint of one esmm call at blocks ``(bm, bk, bn)``."""
+    f32 = 4
+    blocks = [((bm, bk), x_itemsize), ((bk, bn), w_itemsize),
+              ((bm, bn), x_itemsize)]
+    if has_bias:
+        blocks.append(((1, bn), x_itemsize))
+    # The accumulator and the dot's result; a quantized tile is widened
+    # to f32 beside its broadcast scales.
+    scratch = [((bm, bn), f32)] * 2
+    if quant_tiles is not None:
+        blocks.append(((bk // quant_tiles[0], bn // quant_tiles[1]), f32))
+        scratch += [((bk, bn), f32)] * 2
+    return tiles.vmem_bytes(blocks, scratch)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("transpose_rhs", "bm", "bn", "bk", "interpret"),
@@ -86,8 +129,8 @@ def esmm_pallas(
     w_scales: Optional[jax.Array] = None,
     transpose_rhs: bool = False,
     bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
+    bn: int | None = None,
+    bk: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Grouped matmul ys = xs @ W[e] (+ b[e]) on the sorted layout.
@@ -96,6 +139,8 @@ def esmm_pallas(
     b: (E, D2) or None; block_expert: (Np // bm,). ``w_scales``
     (E, n1, n2): block-wise scales of a quantized ``w`` (same axis order
     as w) — dequantized tile-wise in VMEM before the MXU contraction.
+    ``bn``/``bk`` default to ``esmm_blocks``: the whole expert where it
+    fits VMEM, so its weights cross HBM once per run of its row blocks.
     """
     if interpret is None:
         interpret = pallas_interpret_default()
@@ -106,8 +151,17 @@ def esmm_pallas(
         e, d1w, d2 = w.shape
     assert d1w == d1, (w.shape, xs.shape)
     bm = min(bm, np_rows)
-    bn = min(bn, d2)
-    bk = min(bk, d1)
+    quant_tiles = None
+    if w_scales is not None:
+        n1, n2 = w_scales.shape[1:][::-1] if transpose_rhs \
+            else w_scales.shape[1:]
+        quant_tiles = (d1 // n1, d2 // n2)
+    auto_bk, auto_bn = esmm_blocks(
+        bm, d1, d2, x_itemsize=xs.dtype.itemsize,
+        w_itemsize=w.dtype.itemsize, quant_tiles=quant_tiles,
+        has_bias=b is not None)
+    bn = min(bn or auto_bn, d2)
+    bk = min(bk or auto_bk, d1)
     assert np_rows % bm == 0 and d2 % bn == 0 and d1 % bk == 0, (
         f"shapes ({np_rows},{d1})x({d2}) not divisible by blocks {bm, bn, bk}"
     )
@@ -147,14 +201,21 @@ def esmm_pallas(
         args.append(b.reshape(e, 1, d2))
 
     flops = 2 * np_rows * d1 * d2
+    # A whole-expert weight block is fetched once per run of its expert's
+    # row blocks (at most E runs); a split one once per row block, and
+    # then xs is read once per column block unless bk spans D1.
+    w_fetches = min(grid[0], e) if (bk, bn) == (d1, d2) else grid[0]
     bytes_accessed = (
-        xs.size * xs.dtype.itemsize
-        + grid[0] * d1 * d2 * w.dtype.itemsize  # one expert tile per m-block
+        (1 if bk == d1 else grid[1]) * xs.size * xs.dtype.itemsize
+        + w_fetches * d1 * d2 * w.dtype.itemsize
         + np_rows * d2 * xs.dtype.itemsize
     )
     if w_scales is not None:
-        bytes_accessed += grid[0] * int(
+        bytes_accessed += w_fetches * int(
             w_scales.shape[1] * w_scales.shape[2]) * 4
+    vmem = esmm_vmem(bm, bk, bn, x_itemsize=xs.dtype.itemsize,
+                     w_itemsize=w.dtype.itemsize, quant_tiles=quant_tiles,
+                     has_bias=b is not None)
 
     return pl.pallas_call(
         kernel,
@@ -168,6 +229,7 @@ def esmm_pallas(
         out_shape=jax.ShapeDtypeStruct((np_rows, d2), xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=tiles.vmem_limit(vmem),
         ),
         cost_estimate=pl.CostEstimate(
             flops=flops, bytes_accessed=bytes_accessed, transcendentals=0

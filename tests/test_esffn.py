@@ -9,8 +9,9 @@ import pytest
 
 from repro.core import espec
 from repro.core.reindex import build_reindex, gather_rows
-from repro.kernels import ops
-from repro.kernels.esffn import esffn_cost, esffn_glu_pallas, esffn_mlp_pallas
+from repro.kernels import ops, tiles
+from repro.kernels.esffn import (
+    esffn_blocks, esffn_cost, esffn_glu_pallas, esffn_mlp_pallas, esffn_vmem)
 
 N, D, F, E, K, BLK = 48, 16, 24, 4, 2, 8
 IMPLS = ["pallas", "blocked", "ref"]
@@ -195,6 +196,48 @@ def test_pallas_kernel_hidden_blocking():
     np.testing.assert_allclose(
         np.asarray(got_tok), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+@pytest.mark.parametrize("case", ["whole", "split"])
+@pytest.mark.parametrize("glu", [True, False])
+def test_pallas_kernel_tile_rule(monkeypatch, case, glu):
+    """The kernel at the tile rule's hidden block (kernels/tiles.py) against
+    the oracle, with two experts over 8 row blocks each spanning several
+    consecutive blocks. "whole": the rule's own budget, bf = F, the
+    expert's weights resident over its blocks; "split": the budget of
+    bf = 128, so the 256-wide hidden splits in two."""
+    n, d, f, e, blk = 32, 128, 256, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    ri = build_reindex(jax.random.randint(ks[0], (n, 1), 0, e),
+                       jax.random.uniform(ks[1], (n, 1)), e, blk)
+    be = np.asarray(ri.block_expert)
+    assert np.any(be[1:] == be[:-1]), be
+    w, x = _weights(e=e, d=d, f=f), _x(n=n, d=d)
+    kw = dict(glu=glu, x_itemsize=4, w_itemsize=4, has_b1=not glu,
+              has_b2=not glu)
+    if case == "split":
+        monkeypatch.setattr(tiles, "VMEM_BUDGET",
+                            esffn_vmem(blk, d, 128, **kw))
+    bf = esffn_blocks(blk, d, f, **kw)
+    assert bf == (f if case == "whole" else 128)
+    xs = gather_rows(x, ri.row_token)
+    blocks = {} if case == "whole" else {"bf": bf}
+    if glu:
+        got = esffn_glu_pallas(xs, ri.row_gate, ri.block_expert,
+                               w["w_gate"], w["w_up"], w["w_down"],
+                               act="silu", **blocks)
+        want = ops.esffn_glu(
+            x, ri.row_token, ri.row_gate, ri.block_expert, ri.padded_counts,
+            w["w_gate"], w["w_up"], w["w_down"], act="silu", impl="ref")
+    else:
+        got = esffn_mlp_pallas(xs, ri.row_gate, ri.block_expert, w["w1"],
+                               w["b1"], w["w2"], w["b2"], act="gelu",
+                               **blocks)
+        want = ops.esffn_mlp(
+            x, ri.row_token, ri.row_gate, ri.block_expert, ri.padded_counts,
+            w["w1"], w["b1"], w["w2"], w["b2"], act="gelu", impl="ref")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_cost_estimate_excludes_hidden_roundtrip():

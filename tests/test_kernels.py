@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 
 from repro.core.reindex import build_reindex, gather_sorted
-from repro.kernels import ref
-from repro.kernels.esfk import esfk_pallas
-from repro.kernels.esmm import esmm_pallas
+from repro.kernels import ref, tiles
+from repro.kernels.esfk import esfk_blocks, esfk_pallas, esfk_vmem
+from repro.kernels.esmm import esmm_blocks, esmm_pallas, esmm_vmem
 from repro.kernels.ess import ess_pallas
 from repro.kernels.estmm import estmm_pallas
 
@@ -116,6 +116,81 @@ def test_esfk_empty_expert_grads_zero():
     assert np.abs(np.asarray(dw[1:])).max() == 0.0
     assert np.abs(np.asarray(db[1:])).max() == 0.0
     assert np.abs(np.asarray(dw[0])).max() > 0.0
+
+
+# The tile rule's blocks (kernels/tiles.py), in interpret mode against the
+# oracle: (n_tokens, k, E, D1, D2, blk) with two experts over 8 row blocks,
+# so each expert spans several consecutive blocks. "whole": the rule's own
+# budget, whole-expert blocks; "split": the budget of 128 x 128 blocks, so
+# it splits 256-wide dims into 128s.
+RULE_SHAPE = (64, 1, 2, 256, 256, 8)
+RULE_CASES = ["whole", "split"]
+
+
+def _consecutive_blocks(ri):
+    be = np.asarray(ri.block_expert)
+    assert np.any(be[1:] == be[:-1]), be
+
+
+def _rule_blocks(monkeypatch, case, blocks_fn, vmem_fn, bm, d1, d2, **kw):
+    if case == "split":
+        monkeypatch.setattr(tiles, "VMEM_BUDGET", vmem_fn(bm, 128, 128, **kw))
+    got = blocks_fn(bm, d1, d2, **kw)
+    assert got == ((d1, d2) if case == "whole" else (128, 128))
+    return got
+
+
+@pytest.mark.parametrize("case", RULE_CASES)
+@pytest.mark.parametrize("transpose", [False, True])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_esmm_pallas_tile_rule(monkeypatch, case, transpose, dtype):
+    n, k, e, d1, d2, blk = RULE_SHAPE
+    ri, xs, w, b, dy = _setup(*RULE_SHAPE, dtype)
+    _consecutive_blocks(ri)
+    x, bias = (dy, None) if transpose else (xs, b)
+    isz = jnp.dtype(dtype).itemsize
+    bk, bn = _rule_blocks(monkeypatch, case, esmm_blocks, esmm_vmem, blk,
+                          x.shape[1], d1 if transpose else d2,
+                          x_itemsize=isz, w_itemsize=isz)
+    # "whole" runs at the kernel's default blocks, "split" names them.
+    blocks = {} if case == "whole" else {"bk": bk, "bn": bn}
+    out = esmm_pallas(x, w, bias, ri.block_expert, transpose_rhs=transpose,
+                      bm=blk, **blocks)
+    want = ref.esmm(x, w, bias, ri.block_expert, transpose_rhs=transpose)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want, np.float32),
+        **_tol(dtype))
+
+
+@pytest.mark.parametrize("case", RULE_CASES)
+@pytest.mark.parametrize("load", ["uniform", "empty"])
+def test_esfk_pallas_tile_rule(monkeypatch, case, load):
+    """dW and db against the oracle at the rule's blocks; with everything
+    routed to expert 0 the other experts' grads are exact zeros."""
+    n, k, e, d1, d2, blk = RULE_SHAPE
+    ri, xs, w, b, dy = _setup(*RULE_SHAPE, jnp.float32)
+    if load == "empty":
+        ri = build_reindex(jnp.zeros((n, k), jnp.int32), jnp.ones((n, k)),
+                           e, blk)
+    # Padding rows are zero rows, as the layout and the backward give them.
+    real = (ri.row_token < n)[:, None]
+    xs, dy = jnp.where(real, xs, 0.0), jnp.where(real, dy, 0.0)
+    _consecutive_blocks(ri)
+    b1, b2 = _rule_blocks(monkeypatch, case, esfk_blocks, esfk_vmem, blk,
+                          d1, d2, itemsize1=4, itemsize2=4)
+    blocks = {} if case == "whole" else {"b1": b1, "b2": b2}
+    dw, db = esfk_pallas(xs, dy, ri.block_expert, ri.padded_counts, bm=blk,
+                         **blocks)
+    np.testing.assert_allclose(
+        np.asarray(dw), np.asarray(ref.estmm(xs, dy, ri.block_expert, e)),
+        **_tol(jnp.float32))
+    np.testing.assert_allclose(
+        np.asarray(db), np.asarray(ref.ess(dy, ri.block_expert, e)),
+        **_tol(jnp.float32))
+    if load == "empty":
+        assert np.abs(np.asarray(dw[1:])).max() == 0.0
+        assert np.abs(np.asarray(db[1:])).max() == 0.0
+        assert np.abs(np.asarray(dw[0])).max() > 0.0
 
 
 @pytest.mark.parametrize("backend,interpret", [

@@ -7,8 +7,10 @@ against a described ``v5e:2x2`` topology and compile it with the TPU
 compiler that ships with libtpu — no chip is needed and nothing runs.
 
 Widths are published ones: qwen3-moe-30b-a3b (d_model 2048, 128 experts,
-expert d_ff 768, 32/4 heads of 128) and Swin-MoE-Small stage 3 (d 384,
-hidden 1536, 8 experts, float32 with biases).
+expert d_ff 768, 32/4 heads of 128) and Swin-MoE-Small stages 3 and 4 (d 384
+and 768, hidden 4d, 8 experts, float32 with biases). The expert kernels run
+at their default blocks (``kernels/tiles.py``), so these compiles check the
+footprint of the whole-expert VMEM tiles against the described chip.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load libtpu at a time, and the
@@ -42,6 +44,9 @@ SD, SF, SE = 384, 1536, 8
 SN = 196 * 32
 SNP = (SN // BLK + SE) * BLK
 SNB = SNP // BLK
+# Swin-MoE-Small at batch 64: (d, hidden, sorted rows) of stages 3 and 4,
+# rows = round_up(tokens + E * (BLK - 1), BLK) for 12,544 and 3,136 tokens.
+SWIN_B64 = {"stage3": (384, 1536, 13568), "stage4": (768, 3072, 4224)}
 # Paged decode: 8 slots, 16-token pages, 64 logical pages per slot.
 B, HQ, HKV, HD, PAGE, NPAGES, MAXP = 8, 32, 4, 128, 16, 512, 64
 
@@ -143,6 +148,38 @@ def test_esfk_compiles(spec, case):
                 spec((SNB,), I32), spec((SE,), I32))
     _compile(lambda a, b, be, c: esfk_pallas(a, b, be, c, interpret=False),
              *args)
+
+
+@pytest.mark.parametrize("stage", sorted(SWIN_B64))
+@pytest.mark.parametrize(
+    "kernel", ["esffn_mlp", "esmm", "esmm_transposed", "esfk"])
+def test_swin_batch64_compiles(spec, stage, kernel):
+    d, f, rows = SWIN_B64[stage]
+    be, w1, w2 = spec((rows // BLK,), I32), spec((SE, d, f), F32), \
+        spec((SE, f, d), F32)
+    if kernel == "esffn_mlp":
+        _compile(
+            lambda x, rg, be, w1, b1, w2, b2: esffn_mlp_pallas(
+                x, rg, be, w1, b1, w2, b2, act="gelu", interpret=False),
+            spec((rows, d), F32), spec((rows,), F32), be, w1,
+            spec((SE, f), F32), w2, spec((SE, d), F32))
+    elif kernel == "esmm":
+        # z = xs @ w1 + b1 in the forward's recompute
+        _compile(
+            lambda x, w, b, be: esmm_pallas(x, w, b, be, interpret=False),
+            spec((rows, d), F32), w1, spec((SE, f), F32), be)
+    elif kernel == "esmm_transposed":
+        # dX = dz @ w1^T
+        _compile(
+            lambda x, w, be: esmm_pallas(
+                x, w, None, be, transpose_rhs=True, interpret=False),
+            spec((rows, f), F32), w1, be)
+    else:
+        # (dW1, db1) from (xs, dz)
+        _compile(
+            lambda a, b, be, c: esfk_pallas(a, b, be, c, interpret=False),
+            spec((rows, d), F32), spec((rows, f), F32), be,
+            spec((SE,), I32))
 
 
 def test_estmm_compiles(spec):
