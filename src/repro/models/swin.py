@@ -31,6 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.core import baselines, espec
 from repro.core.routing import route
+from repro.kernels.window_attention import window_attention
 from repro.parallel.moe_parallel import (
     MOE_PARAM_LOGICAL,
     MoEParams,
@@ -191,24 +192,19 @@ def _rel_bias_index(window):
 
 
 def _window_attention(p, x, heads, window, eps):
-    """x: (B, H, W, C) -> same, windowed MSA."""
+    """x: (B, H, W, C) -> same, windowed MSA. The partition, the two
+    projections and the reverse are XLA; the attention between the
+    projections is one Pallas kernel (``kernels.window_attention``)."""
     b, h, w, c = x.shape
     window = min(window, h, w)  # Swin clamps when window > feature map
-    hd = c // heads
     nwh, nww = h // window, w // window
     xw = x.reshape(b, nwh, window, nww, window, c)
-    xw = xw.transpose(0, 1, 3, 2, 4, 5).reshape(-1, window * window, c)
+    # (windows x w2 tokens, C): window after window.
+    xw = xw.transpose(0, 1, 3, 2, 4, 5).reshape(-1, c)
 
     qkv = xw @ p["qkv_w"].astype(xw.dtype) + p["qkv_b"].astype(xw.dtype)
-    q, k, v = jnp.split(qkv.reshape(-1, window * window, 3, heads, hd), 3, 2)
-    q, k, v = (t[:, :, 0] for t in (q, k, v))  # (nB, w2, heads, hd)
-    logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * hd ** -0.5
     bias = p["rel_bias"][_rel_bias_index(window)]  # (w2, w2, heads)
-    logits = logits + bias.transpose(2, 0, 1)[None]
-    attn = jax.nn.softmax(logits, -1).astype(xw.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(-1, window * window, c)
+    out = window_attention(qkv, bias, heads)
     out = out @ p["proj_w"].astype(xw.dtype) + p["proj_b"].astype(xw.dtype)
 
     out = out.reshape(b, nwh, nww, window, window, c)

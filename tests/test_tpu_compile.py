@@ -7,8 +7,9 @@ against a described ``v5e:2x2`` topology and compile it with the TPU
 compiler that ships with libtpu — no chip is needed and nothing runs.
 
 Widths are published ones: qwen3-moe-30b-a3b (d_model 2048, 128 experts,
-expert d_ff 768, 32/4 heads of 128) and Swin-MoE-Small stages 3 and 4 (d 384
-and 768, hidden 4d, 8 experts, float32 with biases). The expert kernels run
+expert d_ff 768, 32/4 heads of 128), Swin-MoE-Small stages 3 and 4 (d 384
+and 768, hidden 4d, 8 experts, float32 with biases) and the window
+attention of its four stages at batch 64. The expert kernels run
 at their default blocks (``kernels/tiles.py``), so these compiles check the
 footprint of the whole-expert VMEM tiles against the described chip.
 
@@ -18,7 +19,10 @@ test workers must all collect the same tests.
 """
 from __future__ import annotations
 
+import importlib.machinery
 import os
+import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +36,12 @@ from repro.kernels.ess import ess_pallas
 from repro.kernels.estmm import estmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.window_attention import (
+    window_attention_blocks,
+    window_attn_bwd_pallas,
+    window_attn_fwd_pallas,
+    windows_per_tile,
+)
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -47,6 +57,10 @@ SNB = SNP // BLK
 # Swin-MoE-Small at batch 64: (d, hidden, sorted rows) of stages 3 and 4,
 # rows = round_up(tokens + E * (BLK - 1), BLK) for 12,544 and 3,136 tokens.
 SWIN_B64 = {"stage3": (384, 1536, 13568), "stage4": (768, 3072, 4224)}
+# Swin-MoE-Small window attention at batch 64, one entry per stage:
+# (windows, tokens per window, 3C, heads); head_dim 32.
+SWIN_ATTN_B64 = {"stage1": (4096, 49, 288, 3), "stage2": (1024, 49, 576, 6),
+                 "stage3": (256, 49, 1152, 12), "stage4": (64, 49, 2304, 24)}
 # Paged decode: 8 slots, 16-token pages, 64 logical pages per slot.
 B, HQ, HKV, HD, PAGE, NPAGES, MAXP = 8, 32, 4, 128, 16, 512, 64
 
@@ -215,3 +229,50 @@ def test_flash_attention_compiles(spec):
     x = spec((2 * HQ, 2048, HD), BF16)
     _compile(lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
              x, x, x)
+
+
+@pytest.mark.parametrize("stage", sorted(SWIN_ATTN_B64))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_window_attention_compiles(spec, stage, direction):
+    nw, n, c3, heads = SWIN_ATTN_B64[stage]
+    # As the model calls it: windows packed into score tiles of t rows.
+    p = windows_per_tile(nw, n)
+    nt, t = nw // p, p * n
+    g = window_attention_blocks(nt, t, c3 // 3, heads, 4)
+    kw = dict(heads=heads, n=t, g=g, interpret=False)
+    qkv, bias = spec((nw * n, c3), F32), spec((heads, t, t), F32)
+    if direction == "fwd":
+        compiled = _compile(
+            lambda qkv, b: window_attn_fwd_pallas(qkv, b, **kw), qkv, bias)
+    else:
+        compiled = _compile(
+            lambda qkv, do, lse, b: window_attn_bwd_pallas(
+                qkv, do, lse, b, **kw),
+            qkv, spec((nw * n, c3 // 3), F32), spec((nt, heads, t), F32),
+            bias)
+    # The op the device trace shows carries the kernel's name, which the
+    # expert kernels' roofline pattern must not count.
+    calls = re.findall(r"%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                       compiled.as_text())
+    assert calls and all(
+        c.startswith(f"window_attn_{direction}_pallas") for c in calls)
+    assert not any(_expert_pattern().search(c) for c in calls)
+
+
+def _expert_pattern():
+    """``PATTERN`` of the expert kernels' roofline metric, read from its
+    file in the benchmark."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                        "metrics", "moe_kernel_roofline.train.py")
+    loader = importlib.machinery.SourceFileLoader("_roofline", path)
+    mod = types.ModuleType(loader.name)
+    loader.exec_module(mod)
+    return mod.PATTERN
+
+
+def test_expert_roofline_pattern_skips_window_attention():
+    pattern = _expert_pattern()
+    assert pattern.search("esfk_pallas.3")
+    for fn in (window_attn_fwd_pallas, window_attn_bwd_pallas):
+        assert not pattern.search(fn.__name__)
+        assert not pattern.search(f"{fn.__name__}.12")
