@@ -8,7 +8,7 @@ One chip runs two phases through the repo's own entry points:
 
   train  Swin-MoE-Small as published (224^2, depths 2/2/18/2, 8 experts
          top-1), ``moe_impl="hexa"`` on the Pallas kernels, batch 32, five
-         AdamW steps (``benchmarks.memory_table.make_train_fn``). Losses
+         AdamW steps (``launch.steps.make_swin_train_step``). Losses
          must be finite. Against the XLA ``blocked`` implementation on the
          same chip, the first loss must agree within ``TRAIN_RTOL`` and the
          gradient of every MoE-block parameter within ``TRAIN_GRAD_REL_L2``.
@@ -41,7 +41,6 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
-sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -119,12 +118,14 @@ def bytes_in_use(device) -> int:
 
 
 def train_phase(cfg, *, batch: int, steps: int, seed: int) -> None:
-    from benchmarks.memory_table import make_loss_fn, make_train_fn
+    from repro.launch import steps as steps_lib
     from repro.models import swin
     from repro.optim import adamw
     from repro.parallel.sharding import ParallelConfig, split_tree
 
-    train, opt_cfg = make_train_fn(cfg, ParallelConfig(impl="pallas"), "hexa")
+    opt_cfg = adamw.OptimizerConfig(master_fp32=False)
+    train = steps_lib.make_swin_train_step(
+        cfg, ParallelConfig(impl="pallas"), opt_cfg)
     key = jax.random.PRNGKey(seed)
     params = jax.jit(lambda k: split_tree(swin.init_swin(k, cfg))[0])(key)
     opt = adamw.init_opt_state(params, opt_cfg)
@@ -141,7 +142,7 @@ def train_phase(cfg, *, batch: int, steps: int, seed: int) -> None:
     # weights; only the MoE blocks' gradients are kept.
     grads = {}
     for impl in ("pallas", "blocked"):
-        loss_fn = make_loss_fn(cfg, ParallelConfig(impl=impl), "hexa")
+        loss_fn = steps_lib.make_swin_loss_fn(cfg, ParallelConfig(impl=impl))
 
         def loss_and_moe_grads(p, x, y, loss_fn=loss_fn):
             loss, g = jax.value_and_grad(loss_fn)(p, x, y)

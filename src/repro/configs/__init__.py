@@ -2,7 +2,7 @@
 
 ``get_config(name)`` returns the exact published configuration;
 ``get_smoke_config(name)`` returns a reduced same-family config for CPU
-smoke tests. ``SHAPES`` defines the assigned input-shape set.
+smoke tests.
 """
 from __future__ import annotations
 

@@ -143,19 +143,3 @@ class ShapeConfig:
     kind: str          # train | prefill | decode
     seq_len: int
     global_batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524288, 1),
-}
-
-# long_500k applicability (DESIGN.md §4): sub-quadratic archs only.
-LONG_CONTEXT_ARCHS = {
-    "jamba_1_5_large_398b",  # hybrid SSM
-    "xlstm_350m",            # SSM
-    "mixtral_8x7b",          # SWA: KV bounded by window
-    "gemma3_12b",            # 5:1 local:global
-}

@@ -11,8 +11,8 @@ degraded chips (stragglers). The runtime's straggler detector feeds observed
 per-device step latencies back into this planner (see ``runtime.straggler``),
 closing the loop the paper leaves manual.
 
-Also includes the latency model used by ``benchmarks/hetero_alloc.py`` to
-reproduce Table 3 / Figure 11's "optimal split minimises latency" result.
+Also includes the latency model behind Table 3 / Figure 11's "optimal
+split minimises latency" result (``step_latency_model``).
 """
 from __future__ import annotations
 

@@ -1,14 +1,17 @@
 """Public expert-specific ops: impl dispatch + custom autodiff.
 
-Three interchangeable implementations of the same zero-redundancy semantics
-over the expert-sorted layout (see ``core.reindex``):
+Three implementations of the same zero-redundancy semantics over the
+expert-sorted layout (see ``core.reindex``), named by ``impl``:
 
-  - ``pallas`` — the paper-faithful TPU kernels (esmm/esfk/ess/estmm);
-    interpret mode on CPU.
-  - ``ragged`` — ``lax.ragged_dot(_general)``: XLA's grouped-GeMM lowering.
-    Used for the multi-pod dry-run/compile path and CPU benchmarks (a Pallas
-    interpret-mode kernel would unroll its grid into the HLO).
-  - ``ref``    — pure-jnp one-hot oracle (tests only).
+  - ``pallas``  — the paper-faithful TPU kernels (esmm/esfk/ess/estmm and
+    the fused esffn); the default on the TPU. Off the TPU the tests run
+    them in interpret mode.
+  - ``blocked`` — batched block-diagonal einsums in XLA; the default off
+    the TPU.
+  - ``ref``     — pure-jnp one-hot oracle that the tests compare against.
+
+``get_default_impl`` chooses between ``pallas`` and ``blocked`` by
+platform; every public op raises ``ValueError`` for any other name.
 
 The backward pass is wired by ``custom_vjp`` exactly as the paper's Table 5:
 dX via ESMM with transposed weights, (dW, db) via the fused ESFK (or the
@@ -28,7 +31,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from repro.common import ACTIVATIONS, on_tpu
 from repro.kernels import ref as _ref
@@ -39,20 +41,10 @@ from repro.kernels.esfk import esfk_pallas
 from repro.kernels.ess import ess_pallas
 from repro.kernels.estmm import estmm_pallas
 
-_DEFAULT_IMPL: Optional[str] = None
 _FUSED_BACKWARD: bool = True
 
 
-def set_default_impl(impl: Optional[str]) -> None:
-    """Set the process-wide default implementation (None = auto)."""
-    global _DEFAULT_IMPL
-    assert impl in (None, "pallas", "ragged", "blocked", "ref")
-    _DEFAULT_IMPL = impl
-
-
 def get_default_impl() -> str:
-    if _DEFAULT_IMPL is not None:
-        return _DEFAULT_IMPL
     return "pallas" if on_tpu() else "blocked"
 
 
@@ -72,11 +64,10 @@ def default_fused_ffn(impl: Optional[str] = None) -> bool:
 # Exploits the sorted layout's invariant directly in XLA: every BLK-row
 # block uses ONE expert, so the grouped matmul is a plain batched matmul
 # against per-block gathered weight tiles. Compiled FLOPs equal the
-# zero-redundancy count (Np * D1 * D2 * 2) exactly — unlike
-# lax.ragged_dot, whose CPU lowering computes every group densely (E x
-# redundancy). This is both the dry-run compile path and the fastest
-# CPU execution path; on TPU the Pallas kernels replace it (the per-block
-# weight gather becomes the scalar-prefetched DMA).
+# zero-redundancy count (Np * D1 * D2 * 2) exactly — unlike XLA's grouped
+# dot, whose CPU lowering computes every group densely (E x redundancy).
+# This is the CPU execution path; on TPU the Pallas kernels replace it
+# (the per-block weight gather becomes the scalar-prefetched DMA).
 # ---------------------------------------------------------------------------
 
 def _blocked_esmm(xs, w, b, block_expert, transpose_rhs, w_scales=None):
@@ -117,60 +108,7 @@ def _blocked_estmm(x1, x2, block_expert, num_experts):
     return out.at[block_expert].add(per_block)
 
 
-def set_fused_backward(fused: bool) -> None:
-    """Toggle the ESFK fusion (paper Fig. 12 'fused kernel' ablation)."""
-    global _FUSED_BACKWARD
-    _FUSED_BACKWARD = fused
-
-
-# ---------------------------------------------------------------------------
-# ragged (lax.ragged_dot) implementation
-# ---------------------------------------------------------------------------
-
-def _full_group_sizes(padded_counts: jax.Array, np_rows) -> jax.Array:
-    """Group sizes covering *all* rows: the tail (static over-allocation past
-    the last group) is absorbed into the final group so no row is left with
-    unspecified output. Tail rows are all-zero sentinels, so this is exact."""
-    tail = np_rows - jnp.sum(padded_counts)
-    return padded_counts.at[-1].add(tail.astype(padded_counts.dtype))
-
-
-def _ragged_esmm(xs, w, b, block_expert, padded_counts, transpose_rhs):
-    np_rows = xs.shape[0]
-    gs = _full_group_sizes(padded_counts, np_rows)
-    if transpose_rhs:
-        dn = lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((1,), (2,)), ((), ())),
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[0],
-        )
-        y = lax.ragged_dot_general(
-            xs, w, gs, dn, preferred_element_type=xs.dtype
-        )
-    else:
-        y = lax.ragged_dot(xs, w, gs, preferred_element_type=xs.dtype)
-    if b is not None:
-        nblk = block_expert.shape[0]
-        blk = np_rows // nblk
-        y = (
-            y.reshape(nblk, blk, -1) + b[block_expert][:, None].astype(y.dtype)
-        ).reshape(np_rows, -1)
-    return y
-
-
-def _ragged_estmm(x1, x2, padded_counts):
-    gs = _full_group_sizes(padded_counts, x1.shape[0])
-    dn = lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0],
-        rhs_group_dimensions=[],
-    )
-    return lax.ragged_dot_general(
-        x1, x2, gs, dn, preferred_element_type=jnp.float32
-    )
-
-
-def _ragged_ess(x, block_expert, num_experts):
+def _blocked_ess(x, block_expert, num_experts):
     blk = x.shape[0] // block_expert.shape[0]
     row_expert = jnp.repeat(block_expert, blk)
     return jax.ops.segment_sum(
@@ -179,6 +117,12 @@ def _ragged_ess(x, block_expert, num_experts):
         num_segments=num_experts,
         indices_are_sorted=True,
     )
+
+
+def set_fused_backward(fused: bool) -> None:
+    """Toggle the ESFK fusion (paper Fig. 12 'fused kernel' ablation)."""
+    global _FUSED_BACKWARD
+    _FUSED_BACKWARD = fused
 
 
 # ---------------------------------------------------------------------------
@@ -196,12 +140,10 @@ def _esmm_any(impl, transpose_rhs, xs, w, b, block_expert, padded_counts,
     if impl == "blocked":
         return _blocked_esmm(xs, w, b, block_expert, transpose_rhs,
                              w_scales=w_scales)
-    if w_scales is not None:
-        # ragged/ref: semantics references — dequantize up front.
-        w = dequantize_blockwise(w, w_scales, dtype=xs.dtype)
-    if impl == "ragged":
-        return _ragged_esmm(xs, w, b, block_expert, padded_counts, transpose_rhs)
     if impl == "ref":
+        if w_scales is not None:
+            # The oracle dequantizes up front.
+            w = dequantize_blockwise(w, w_scales, dtype=xs.dtype)
         return _ref.esmm(xs, w, b, block_expert, transpose_rhs=transpose_rhs)
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -221,13 +163,9 @@ def _esfk_any(impl, fused, x1, x2, block_expert, padded_counts, need_db):
             else None
         )
         return dw, db
-    if impl == "ragged":
-        dw = _ragged_estmm(x1, x2, padded_counts)
-        db = _ragged_ess(x2, block_expert, e) if need_db else None
-        return dw, db
     if impl == "blocked":
         dw = _blocked_estmm(x1, x2, block_expert, e)
-        db = _ragged_ess(x2, block_expert, e) if need_db else None
+        db = _blocked_ess(x2, block_expert, e) if need_db else None
         return dw, db
     if impl == "ref":
         dw = _ref.estmm(x1, x2, block_expert, e)
@@ -343,7 +281,7 @@ def esmm(
     return _esmm(impl, transpose_rhs, fused, xs, w, b, block_expert, padded_counts)
 
 
-# Non-differentiable public wrappers (tests / ablation benchmarks).
+# Non-differentiable public wrappers (the quantized backward, tests).
 
 def ess(x, block_expert, padded_counts, *, impl=None):
     impl = impl or get_default_impl()
@@ -351,9 +289,11 @@ def ess(x, block_expert, padded_counts, *, impl=None):
     if impl == "pallas":
         blk = x.shape[0] // block_expert.shape[0]
         return ess_pallas(x, block_expert, padded_counts, bm=blk)
-    if impl in ("ragged", "blocked"):
-        return _ragged_ess(x, block_expert, e)
-    return _ref.ess(x, block_expert, e)
+    if impl == "blocked":
+        return _blocked_ess(x, block_expert, e)
+    if impl == "ref":
+        return _ref.ess(x, block_expert, e)
+    raise ValueError(f"unknown impl {impl!r}")
 
 
 def estmm(x1, x2, block_expert, padded_counts, *, impl=None):
@@ -362,11 +302,11 @@ def estmm(x1, x2, block_expert, padded_counts, *, impl=None):
     if impl == "pallas":
         blk = x1.shape[0] // block_expert.shape[0]
         return estmm_pallas(x1, x2, block_expert, padded_counts, bm=blk)
-    if impl == "ragged":
-        return _ragged_estmm(x1, x2, padded_counts)
     if impl == "blocked":
         return _blocked_estmm(x1, x2, block_expert, e)
-    return _ref.estmm(x1, x2, block_expert, e)
+    if impl == "ref":
+        return _ref.estmm(x1, x2, block_expert, e)
+    raise ValueError(f"unknown impl {impl!r}")
 
 
 def esfk(x1, x2, block_expert, padded_counts, *, impl=None, fused=True):
@@ -381,7 +321,7 @@ def esfk(x1, x2, block_expert, padded_counts, *, impl=None, fused=True):
 #   pallas  — kernels.esffn megakernel (the (Np, F) hidden never hits HBM).
 #   blocked — one fused XLA region: rows gathered straight from the unsorted
 #             x, expert weight tiles formed by exact one-hot contraction.
-#   ragged/ref — staged composition inside the op (semantics reference).
+#   ref     — staged composition of the oracle's esmms inside the op.
 # Backward (all impls) is flash-style: residuals are xs-level only (x +
 # row maps + weights); the hidden is recomputed and grads flow through the
 # same ESMM/ESFK kernels as the unfused path (paper Table 5 wiring).
@@ -468,7 +408,7 @@ def _blocked_esffn_mlp(x, row_token, row_gate, block_expert, w1, b1, w2, b2,
 
 def _staged_esffn(impl, act_fn, x, row_token, row_gate, block_expert,
                   padded_counts, glu, ws, scales=None):
-    """Per-stage composition inside the fused op (ragged / ref impls)."""
+    """Per-stage composition inside the fused op (the ``ref`` impl)."""
     xs = _gather_rows(x, row_token)
     if glu:
         wg, wu, wd = ws
@@ -510,7 +450,7 @@ def _esffn_fwd_any(impl, act, glu, x, row_token, row_gate, block_expert,
             x, row_token, row_gate, block_expert, *ws, act_fn=act_fn,
             scales=scales,
         )
-    if impl in ("ragged", "ref"):
+    if impl == "ref":
         return _staged_esffn(
             impl, act_fn, x, row_token, row_gate, block_expert,
             padded_counts, glu, ws, scales=scales,

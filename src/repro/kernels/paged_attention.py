@@ -402,9 +402,9 @@ def paged_attention(
     impl: Optional[str] = None,
 ) -> jax.Array:
     """Impl dispatch, mirroring ``kernels.ops``: "pallas" | "blocked" |
-    "ref"/"gather" (default off-TPU: the gather-dense reference — on CPU the
-    page gather is memory-bound either way and the dense reduction is what
-    the parity matrix pins). ``k_scale``/``v_scale``: int8-pool
+    "ref" (the gather-dense reference — on CPU the page gather is
+    memory-bound either way and the dense reduction is what the parity
+    matrix pins). ``k_scale``/``v_scale``: int8-pool
     per-(row, head) scales (DESIGN.md §8)."""
     from repro.kernels import ops
 
@@ -417,7 +417,7 @@ def paged_attention(
     if impl == "blocked":
         return paged_attention_blocked(q, k_pool, v_pool, page_table,
                                        lengths, **kw)
-    if impl in ("ref", "gather", "ragged"):
+    if impl == "ref":
         return paged_attention_ref(q, k_pool, v_pool, page_table,
                                    lengths, **kw)
     raise ValueError(f"unknown paged attention impl {impl!r}")

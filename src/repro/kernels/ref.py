@@ -1,6 +1,6 @@
 """Pure-jnp oracles for the expert-specific operators.
 
-These are the ground truth every other implementation (Pallas, ragged) is
+These are the ground truth every other implementation (Pallas, blocked) is
 tested against. They operate on the *sorted layout* produced by
 ``core.reindex`` and are deliberately simple (one-hot einsums); never use
 them on real workloads.

@@ -1,7 +1,7 @@
 """input_specs(): ShapeDtypeStruct stand-ins for every model input.
 
-Weak-type-correct, shardable, zero allocation — the dry-run lowers every
-(arch x shape x mesh) cell against these. The same functions build real
+Weak-type-correct, shardable, zero allocation — the training driver and
+the step builders shard against these. The same functions build real
 arrays for smoke tests (``concrete=True`` path in tests).
 """
 from __future__ import annotations
@@ -14,7 +14,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import lm
 from repro.parallel.sharding import ParallelConfig, divisible_spec, resolve_spec
 
 
@@ -82,39 +81,6 @@ def input_specs(
             out["labels"] = _sharded(toks(b, s), (("dp",), "sp"), pcfg, mesh)
         out["loss_mask"] = _sharded(f32(b, s), (("dp",), "sp"), pcfg, mesh)
     return out
-
-
-def cache_specs(
-    cfg: ModelConfig,
-    shape: ShapeConfig,
-    pcfg: ParallelConfig,
-    mesh: Optional[Mesh],
-):
-    """Abstract (sharded) decode cache for one cell."""
-    spec_tree = lm.cache_spec(cfg, shape.global_batch, shape.seq_len)
-    logical = lm.cache_logical_specs(cfg, spec_tree)
-    if mesh is None:
-        return spec_tree
-
-    def apply(sds, logical_spec):
-        phys = divisible_spec(
-            sds.shape, resolve_spec(logical_spec, pcfg, mesh), mesh
-        )
-        return jax.ShapeDtypeStruct(
-            sds.shape, sds.dtype, sharding=NamedSharding(mesh, phys)
-        )
-
-    layers = [
-        {
-            k: apply(spec_tree["layers"][pos][k], logical["layers"][pos][k])
-            for k in spec_tree["layers"][pos]
-        }
-        for pos in range(len(spec_tree["layers"]))
-    ]
-    return {
-        "layers": layers,
-        "len": apply(spec_tree["len"], logical["len"]),
-    }
 
 
 def concrete_inputs(cfg: ModelConfig, shape: ShapeConfig, seed: int = 0) -> dict:

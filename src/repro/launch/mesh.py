@@ -1,19 +1,12 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 All functions (never module-level constants) so importing this module never
-touches jax device state — required because the dry-run must set
+touches jax device state — a process that fakes a CPU mesh must set
 XLA_FLAGS before any jax initialisation.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def split_model_axis(shape, axes, node_size: int):

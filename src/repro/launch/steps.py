@@ -1,5 +1,6 @@
 """Step builders: train_step (loss+grads+optimizer), prefill_step,
-serve_step — the functions the dry-run lowers and the drivers execute.
+serve_step — the functions the drivers execute — and the Swin-MoE
+classification train step.
 """
 from __future__ import annotations
 
@@ -12,7 +13,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.inputs import activation_spec
-from repro.models import lm
+from repro.models import lm, swin
 from repro.obs import device as obs_device
 from repro.optim import adamw
 from repro.parallel.sharding import (
@@ -159,6 +160,37 @@ def make_train_step(
             params, grads, opt_state, opt_cfg
         )
         return params, opt_state, {**metrics, **om, "total_loss": total}
+
+    return train_step
+
+
+def make_swin_loss_fn(cfg: swin.SwinConfig, pcfg: ParallelConfig):
+    """Swin-MoE classification loss ``(params, images, labels) -> loss``:
+    cross-entropy against one-hot labels plus ``cfg.moe.aux_weight`` times
+    the load-balancing loss, through ``swin.swin_forward``'s hexa path."""
+
+    def loss_fn(params, images, labels):
+        logits, aux, _ = swin.swin_forward(params, images, cfg, pcfg, None)
+        onehot = jax.nn.one_hot(labels, cfg.num_classes)
+        ce = -jnp.mean(jnp.sum(jax.nn.log_softmax(logits) * onehot, -1))
+        return ce + cfg.moe.aux_weight * aux
+
+    return loss_fn
+
+
+def make_swin_train_step(cfg: swin.SwinConfig, pcfg: ParallelConfig,
+                         opt_cfg: adamw.OptimizerConfig):
+    """Build the jittable Swin-MoE train step: value_and_grad of
+    ``make_swin_loss_fn`` followed by the AdamW update,
+    ``(params, opt_state, images, labels) -> (params, opt_state, loss)``."""
+    loss_fn = make_swin_loss_fn(cfg, pcfg)
+
+    def train_step(params, opt_state, images, labels):
+        loss, grads = jax.value_and_grad(loss_fn)(params, images, labels)
+        params, opt_state, _ = adamw.apply_updates(
+            params, grads, opt_state, opt_cfg
+        )
+        return params, opt_state, loss
 
     return train_step
 

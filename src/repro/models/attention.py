@@ -5,10 +5,8 @@ query chunks where chunk c only reads K/V[0 : (c+1)*chunk] (static slice), so
 the compiled FLOPs are ~half of a masked full-S^2 implementation and sliding
 windows become genuinely sub-quadratic (chunk c reads a static window slice).
 Within a chunk an online-softmax scan over KV blocks bounds live memory to
-(chunk x kv_block) logits — the pure-XLA shape of flash attention, chosen
-over a Pallas kernel because the multi-pod dry-run must lower through XLA on
-CPU (DESIGN.md §2); a Pallas flash kernel would unroll its grid in interpret
-mode.
+(chunk x kv_block) logits — the pure-XLA shape of flash attention
+(DESIGN.md §2).
 
 Decode attention is a plain einsum over the cache: O(S·d) memory-bound work
 that GSPMD shards (sequence-sharded caches combine via partial-softmax

@@ -4,8 +4,8 @@ KV/state cache management, and loss.
 Layers are grouped into *periods* (the lcm of the layer/attention/MoE
 patterns); parameters are stacked over periods and the forward runs a
 ``lax.scan`` over periods with the blocks of one period unrolled inside.
-This keeps the HLO size O(period) regardless of depth — essential for the
-72-layer Jamba dry-run — and is where the remat policy (the paper's
+This keeps the HLO size O(period) regardless of depth — essential for
+72-layer stacks such as Jamba — and is where the remat policy (the paper's
 pipeline-shared cache, DESIGN.md §2) is applied.
 """
 from __future__ import annotations
@@ -185,34 +185,6 @@ def cache_spec(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
         "layers": layers,
         "len": jax.ShapeDtypeStruct((batch,), jnp.int32),
     }
-
-
-def cache_logical_specs(cfg: ModelConfig, cache: dict) -> dict:
-    """Logical partition specs for the cache tree (batch -> dp; the cache
-    sequence dim -> sp so long contexts shard; states shard inner dims)."""
-    def leaf_spec(path_leaf):
-        s = path_leaf.shape if hasattr(path_leaf, "shape") else None
-        return s
-
-    layers = []
-    period = cfg.period
-    for pos in range(period):
-        kind = cfg.layer_kind(pos)
-        if kind == "attn":
-            spec = {"k": (None, "dp", "sp", None, None),
-                    "v": (None, "dp", "sp", None, None)}
-        elif kind == "mamba":
-            spec = {"conv": (None, "dp", None, "tp"),
-                    "ssm": (None, "dp", "tp", None)}
-        elif kind == "mlstm":
-            spec = {"c": (None, "dp", None, None, None),
-                    "n": (None, "dp", None, None),
-                    "m": (None, "dp", None),
-                    "conv": (None, "dp", None, "tp")}
-        else:  # slstm
-            spec = {k: (None, "dp", None, None) for k in ("c", "n", "h", "m")}
-        layers.append(spec)
-    return {"layers": layers, "len": ("dp",)}
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:

@@ -50,7 +50,7 @@ def configure(metrics: bool = True, tracing: bool = True,
     """Enable (or disable) the process-wide observability instances.
 
     ``reset`` clears previously recorded spans/events — drivers use it so
-    back-to-back runs in one process (tests, benchmarks) start clean."""
+    back-to-back runs in one process (tests) start clean."""
     registry.enabled = metrics
     tracer.enabled = tracing
     events.enabled = event_log

@@ -7,8 +7,7 @@ execution (all-gather expert weights; tokens stationary). Model-centric wins
 small workloads, data-centric wins large ones, and the crossover sits where
 moved token bytes ≈ moved weight bytes.
 
-This module promotes the offline roofline (``benchmarks/centric_crossover.py``
-now imports it from here) into a per-layer *runtime* decision:
+This module turns that roofline into a per-layer *runtime* decision:
 
   * ``layer_latency`` — the roofline itself: max(compute, HBM, link) for one
     MoE FFN layer under a given mode. Byte/FLOP terms only; no device state.
@@ -208,8 +207,7 @@ def moe_coll_bytes(
 
     The flat schedule's ring spans nodes, so its whole per-device volume is
     paced by (and billed to) the inter level; the hierarchical schedule
-    splits per DESIGN.md §10 — this is what ``benchmarks/hetero_alloc.py``
-    feeds the simulated per-link latencies to pin hier <= flat."""
+    splits per DESIGN.md §10."""
     tok_bytes = float(tokens) * d * 2
     w_bytes = float(e) * 2 * d * f * (weight_bits / 8)
     n = max(int(n_dev), 1)
@@ -513,9 +511,9 @@ def serve_decode_attn_latency(
 ) -> float:
     """Roofline latency of one decode-attention macro-step: decode
     attention does O(1) FLOPs per byte, so the HBM term is the whole bill.
-    This is the cost-model entry that lets the serving driver (and
-    ``benchmarks/serve_bench.py``) price the paged kernel against the
-    dense layout for an actual mix of sequence lengths."""
+    This is the cost-model entry that lets the serving driver price the
+    paged kernel against the dense layout for an actual mix of sequence
+    lengths."""
     return decode_attn_bytes(
         kind, num_slots=num_slots, max_seq=max_seq, hq=hq, hkv=hkv, hd=hd,
         lengths=lengths, page=page, itemsize=itemsize,
@@ -584,8 +582,7 @@ def spec_decode_speedup(
     tokens per round (``expected_verify_tokens``) divided by the verify
     round's cost relative to a single decode step. >1 exactly when the
     per-round token gain outruns the (sub-linear, memory-bound) cost of
-    scoring ``spec_k + 1`` rows at once — the model-side version of the
-    measured ``serve/spec/{on,off}`` rows in ``BENCH_serve.json``. Draft
+    scoring ``spec_k + 1`` rows at once. Draft
     cost is not included (the n-gram drafter is host-side and free; a
     draft model adds its own, much smaller, roofline)."""
     dec = spec_verify_latency(1, d, f, e, k, n_dev=n_dev, hw=hw,
@@ -716,8 +713,7 @@ def plan_layer_modes(model_cfg, cfg, mesh, tokens: int) -> Tuple[Optional[str], 
     """Whole-model plan: one entry per period position (None = not MoE).
 
     Pin the result into ``ParallelConfig.layer_mode_plan`` to freeze the
-    decision (e.g. for the dry-run, or to ship a serving config that never
-    re-derives it).
+    decision (e.g. to ship a serving config that never re-derives it).
     """
     if model_cfg.moe is None:
         return ()

@@ -18,7 +18,7 @@ all-gather therefore precedes, and is data-independent of, layer-l compute —
 exactly the overlap XLA's latency-hiding scheduler needs — while eviction
 drops the last reference to layer l-C+1's gathered buffers, bounding their
 liveness. Residency accounting (resident/peak layers and bytes, hit/miss
-counters) is exposed so ``benchmarks/memory_table.py`` can report it.
+counters) is exposed for the tests that bound it.
 
 The gather itself is ``gather_ffn_params``: a GSPMD-level all-gather
 expressed as a sharding constraint that drops the "fsdp" factor from each
@@ -407,11 +407,6 @@ class PagePool:
 
     def group_free(self, group: int) -> int:
         return self._free[group]
-
-    def reset_peak(self) -> None:
-        """Restart peak tracking from the current residency (benchmarks
-        call this after their warm-up workload)."""
-        self.peak_in_use_pages = self.in_use_pages
 
     def reshare(self, shares: Sequence[int]) -> None:
         """Rebind the per-group share budgets — the elastic-shrink path

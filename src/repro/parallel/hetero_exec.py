@@ -25,8 +25,7 @@ two agree numerically (tier-1 asserts it).
 the plan's relative latencies — a *simulated-skew mesh*: the kernels run
 for real at the uneven shapes on this host, and device i's clock runs
 ``t_i/t_min`` slower. The synchronous step latency is the max (the
-all-reduce barrier), which is how ``benchmarks/hetero_alloc.py`` shows the
-proportional split beating uniform with measured, not modelled, numbers.
+all-reduce barrier).
 """
 from __future__ import annotations
 

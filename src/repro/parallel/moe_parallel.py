@@ -1,7 +1,7 @@
 """Distributed MoE layer execution — the paper's §4.3 on a TPU mesh.
 
 One shard_map island per MoE layer gives exact control of the collective
-schedule (auditable in the dry-run HLO):
+schedule (auditable in the compiled HLO):
 
   model-centric (paper TP): expert hidden dim sharded over "model"; tokens
     all-gathered over "model"; partial outputs reduced. NO weight movement.

@@ -31,7 +31,7 @@ class Param:
     """A parameter leaf: value (or ShapeDtypeStruct) + logical spec.
 
     Registered as a pytree node with ``spec`` as static aux data, so Param
-    trees pass through jax.eval_shape (abstract init for the dry-run).
+    trees pass through jax.eval_shape (abstract init).
     """
     __slots__ = ("value", "spec")
 

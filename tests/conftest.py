@@ -1,7 +1,7 @@
 import os
 import sys
 
-# Tests and benches see ONE device (the dry-run sets its own XLA_FLAGS in a
+# Tests see ONE device (multi-device tests set their own XLA_FLAGS in a
 # subprocess). Keep CPU compile fast.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -56,16 +56,6 @@ def test_choose_mode_matches_roofline_crossover():
         assert autotune.choose_mode(t, D, F, E, K, n_dev=16) == expect
 
 
-def test_benchmark_uses_the_same_cost_model():
-    """benchmarks/centric_crossover must import (not fork) the roofline."""
-    sys.path.insert(0, ROOT)
-    try:
-        from benchmarks import centric_crossover
-    finally:
-        sys.path.remove(ROOT)
-    assert centric_crossover.layer_latency is autotune.layer_latency
-
-
 def test_small_workload_prefers_model_centric_large_prefers_data():
     assert autotune.choose_mode(64, D, F, E, K, n_dev=16) == "model_centric"
     assert autotune.choose_mode(2 ** 17, D, F, E, K, n_dev=16) == "data_centric"

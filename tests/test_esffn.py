@@ -270,7 +270,6 @@ def test_cost_estimate_excludes_hidden_roundtrip():
 def test_default_fused_on_for_pallas_only():
     assert ops.default_fused_ffn("pallas") is True
     assert ops.default_fused_ffn("blocked") is False
-    assert ops.default_fused_ffn("ragged") is False
     assert ops.default_fused_ffn("ref") is False
 
 

@@ -1,5 +1,5 @@
-"""Per-kernel allclose sweeps: Pallas (interpret) and ragged vs the pure-jnp
-oracle, across shapes and dtypes (the deliverable-(c) kernel contract)."""
+"""Per-kernel allclose sweeps: Pallas (interpret) vs the pure-jnp oracle,
+across shapes and dtypes (the deliverable-(c) kernel contract)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -231,3 +231,42 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
         assert common.enable_compile_cache() == str(tmp_path / env)
         assert calls == []
+
+
+def _entry_points():
+    """One call per public expert-op and paged-attention entry point, each
+    taking the kernel path by name."""
+    from repro.kernels import ops
+    from repro.kernels.paged_attention import paged_attention
+
+    ri, xs, w, b, dy = _setup(16, 1, 2, 16, 16, 8, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 16))
+    wt = jnp.swapaxes(w, 1, 2)
+    groups = (ri.block_expert, ri.padded_counts)
+    rows = (x, ri.row_token, ri.row_gate, *groups)
+    pool = jnp.zeros((5, 8, 2, 16))
+    table = jnp.arange(1, 5, dtype=jnp.int32).reshape(2, 2)
+    return {
+        "esmm": lambda impl: ops.esmm(xs, w, b, *groups, impl=impl),
+        "esfk": lambda impl: ops.esfk(xs, dy, *groups, impl=impl),
+        "ess": lambda impl: ops.ess(dy, *groups, impl=impl),
+        "estmm": lambda impl: ops.estmm(xs, dy, *groups, impl=impl),
+        "esffn_glu": lambda impl: ops.esffn_glu(*rows, w, w, wt, impl=impl),
+        "esffn_mlp": lambda impl: ops.esffn_mlp(*rows, w, b, wt, b,
+                                                impl=impl),
+        "paged_attention": lambda impl: paged_attention(
+            jnp.zeros((2, 1, 4, 16)), pool, pool, table,
+            jnp.array([3, 9], jnp.int32), impl=impl),
+    }
+
+
+@pytest.mark.parametrize("entry", ["esmm", "esfk", "ess", "estmm",
+                                   "esffn_glu", "esffn_mlp",
+                                   "paged_attention"])
+def test_unknown_impl_is_refused(entry):
+    """Each public entry point runs ``pallas``, ``blocked`` or ``ref`` and
+    refuses any other name rather than falling back to a slower path."""
+    call = _entry_points()[entry]
+    call("ref")  # the same arguments run under a known impl
+    with pytest.raises(ValueError, match="unknown"):
+        call("ragged")

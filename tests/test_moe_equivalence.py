@@ -30,7 +30,7 @@ def _params(e, d, f, glu, seed=0):
 N, D, F, E, K, BLK = 48, 16, 24, 4, 2, 8
 
 
-@pytest.mark.parametrize("impl", ["ragged", "blocked", "pallas", "ref"])
+@pytest.mark.parametrize("impl", ["blocked", "pallas", "ref"])
 @pytest.mark.parametrize("glu", [True, False])
 def test_forward_matches_per_token_oracle(impl, glu):
     p = _params(E, D, F, glu)
@@ -64,7 +64,7 @@ def test_forward_matches_per_token_oracle(impl, glu):
     )
 
 
-@pytest.mark.parametrize("impl", ["ragged", "blocked", "pallas"])
+@pytest.mark.parametrize("impl", ["blocked", "pallas"])
 @pytest.mark.parametrize("fused", [True, False])
 def test_gradients_match_oracle(impl, fused):
     glu = False
@@ -107,7 +107,7 @@ def test_hexa_equals_no_drop_dispatch():
     r = route(x, p["router"], K)
     out = espec.hexa_moe_ffn(
         x, p, num_experts=E, top_k=K, act="gelu", glu=False, blk=BLK,
-        impl="ragged",
+        impl="blocked",
     )
     base = baselines.grouped_dense_moe(
         x, r, p["w1"], p["b1"], p["w2"], p["b2"], act=jax.nn.gelu

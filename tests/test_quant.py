@@ -1,7 +1,7 @@
 """Quantized-expert subsystem (ISSUE 5, DESIGN.md §8): block-wise
 int8/fp8 quant/dequant exactness, STE gradient flow, fused-dequant
 esffn/esmm parity against the dequant-then-dense reference across
-pallas-interpret/blocked/ref/ragged, uneven expert loads, the
+pallas-interpret/blocked/ref, uneven expert loads, the
 weight_bits cost-model terms, precision-aware hetero execution, and the
 island-level QAT / true-quant paths."""
 import dataclasses
@@ -22,7 +22,7 @@ from repro.parallel.moe_parallel import MoEParams, MoEStatic, moe_layer
 from repro.parallel.sharding import ParallelConfig
 from repro.quant import core as qc
 
-IMPLS = ("pallas", "blocked", "ref", "ragged")
+IMPLS = ("pallas", "blocked", "ref")
 
 
 def _setup(seed=0, n=24, d=32, f=48, e=4, k=2, blk=8, glu=True):
